@@ -36,7 +36,6 @@ sys.path.insert(0, str(_ROOT / "src"))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from benchmarks.common import emit, header
 from repro.configs import get_config
@@ -136,7 +135,7 @@ def main(argv=None) -> int:
     specs = lm.model_specs(cfg, tp=args.tp)
 
     def init(key):
-        return init_tree(specs, key, fold=lax.axis_index("model"))
+        return init_tree(specs, key)
 
     params = jax.jit(jax.vmap(init, axis_name="model", axis_size=args.tp,
                               in_axes=None, out_axes=0))(jax.random.key(0))

@@ -53,7 +53,6 @@ sys.path.insert(0, str(_ROOT / "src"))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from benchmarks.common import emit, header
 from repro.configs import get_config
@@ -69,7 +68,7 @@ def make_params(cfg, tp):
     specs = lm.model_specs(cfg, tp=tp)
 
     def init(key):
-        return init_tree(specs, key, fold=lax.axis_index("model"))
+        return init_tree(specs, key)
 
     return jax.jit(jax.vmap(init, axis_name="model", axis_size=tp,
                             in_axes=None, out_axes=0))(jax.random.key(0))

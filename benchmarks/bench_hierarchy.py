@@ -72,7 +72,7 @@ import json, os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro._compat import shard_map
+from jax import shard_map
 from repro.core import api, costmodel as cm
 from repro.core.trace import Trace, TraceEntry
 from repro.launch.mesh import make_host_mesh
@@ -120,6 +120,9 @@ def _run_child(code, *args, timeout=600):
     env = dict(os.environ)
     env["PYTHONPATH"] = str(_ROOT / "src")
     env.pop("XLA_FLAGS", None)
+    # the children model the host tier on forced CPU devices: never let
+    # them reach for a chip a parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", code, *args],
                        capture_output=True, text=True, timeout=timeout,
                        env=env)

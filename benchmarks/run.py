@@ -1,17 +1,20 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived``.
 from __future__ import annotations
 
+import sys
 import traceback
 
 from benchmarks.common import header
 
 
-def main() -> None:
+def main() -> int:
+    """Runs every table; returns 1 when any of them raised."""
     header()
     from benchmarks import (bench_case_allreduce, bench_case_reduce,
                             bench_collective_matmul, bench_decode_profile,
                             bench_dispatch, bench_guidelines, bench_hierarchy,
                             bench_measured, bench_nrep_lookup, bench_roofline)
+    failed = []
     for mod in (bench_guidelines,       # Figs. 3/4/5 violation tables
                 bench_case_reduce,      # Fig. 6 Reduce<=Allreduce case
                 bench_case_allreduce,   # Fig. 7 rs+agv beats everything
@@ -27,7 +30,11 @@ def main() -> None:
         except Exception:
             traceback.print_exc()
             print(f"{mod.__name__},0.0,ERROR")
+            failed.append(mod.__name__)
+    if failed:
+        print(f"benchmarks failed: {failed}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

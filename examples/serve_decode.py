@@ -24,7 +24,6 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from repro.configs import get_config
 from repro.core import api, costmodel, tuner
@@ -83,7 +82,7 @@ def main():
     s_max = args.prompt_len + args.tokens + 8
     specs = lm.model_specs(cfg, tp=args.tp)
     params = jax.jit(jax.vmap(
-        lambda key: init_tree(specs, key, fold=lax.axis_index("model")),
+        lambda key: init_tree(specs, key),
         axis_name="model", axis_size=args.tp, in_axes=None,
         out_axes=0))(jax.random.key(0))
     rng = np.random.default_rng(0)

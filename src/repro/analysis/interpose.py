@@ -503,7 +503,7 @@ def compile_zoo_hlo(arch: str, *, kind: str = "train",
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro._compat import shard_map
+    from jax import shard_map
     from repro.configs import get_config
     from repro.launch.mesh import make_host_mesh
     from repro.launch.shapes import ShapeCell, dp_axes, input_specs

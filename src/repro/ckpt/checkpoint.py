@@ -28,11 +28,9 @@ import threading
 import jax
 import numpy as np
 
-from repro._compat import tree_flatten_with_path
-
 
 def _flatten(tree):
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     out = {}
     for path, leaf in flat:
         key = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
@@ -93,7 +91,7 @@ def restore(ckpt_dir, step: int, like_tree):
     ``jax.device_put`` against the target sharding when present."""
     d = pathlib.Path(ckpt_dir) / f"step_{step:09d}"
     data = np.load(d / "arrays.npz")
-    flat, treedef = tree_flatten_with_path(like_tree)
+    flat, treedef = jax.tree.flatten_with_path(like_tree)
     leaves = []
     for path, like in flat:
         key = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))

@@ -18,10 +18,7 @@ _TLS = threading.local()
 
 def axis_size(axis_name: str) -> int:
     """Static size of a named axis (trace-time Python int)."""
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis_name))
-    # Fallback: psum of a unit literal is folded to the axis size.
-    return int(lax.psum(1, axis_name))
+    return int(lax.axis_size(axis_name))
 
 
 def axis_index(axis_name: str):

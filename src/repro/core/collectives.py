@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-import repro._compat  # noqa: F401  (vmap rule for optimization_barrier)
 from repro.core._axis import (axis_index, axis_size, pshift, ring_perm,
                               shift_perm)
 
@@ -217,7 +216,11 @@ def allgather_as_alltoall(x, axis: str, **_):
     """(GL2) p-times replicated send buffer, then all-to-all."""
     p = axis_size(axis)
     reps = (p,) + (1,) * (x.ndim - 1)
-    big = jnp.tile(x, reps)
+    # the send buffer is materialized: XLA:CPU splits an all-to-all into
+    # per-peer slices, and its simplifier rewrote a slice of a tiled
+    # transpose into a transpose of another layout than its sibling
+    # slices, which the HLO verifier rejects
+    big = lax.optimization_barrier(jnp.tile(x, reps))
     return lax.all_to_all(big, axis, split_axis=0, concat_axis=0, tiled=True)
 
 
@@ -600,14 +603,8 @@ def allgather_matmul_default(x, axis: str, *, w, return_gathered: bool = False,
 def allgather_matmul_fused_ring(x, axis: str, *, w,
                                 return_gathered: bool = False, **_):
     """(⊕) ring allgather-matmul: chunk s+1 in flight while chunk s is on
-    the MXU.  The backend check lives HERE (not at callsites): on TPU the
-    tier-3 in-kernel RDMA ring is used; everywhere else the ppermute
-    reference ring — CPU CI never even imports the RDMA module."""
+    the MXU.  The same ppermute ring on every backend."""
     from repro.kernels import collective_matmul as cmm
-    if cmm.on_tpu():
-        from repro.kernels import collective_matmul_rdma as rdma
-        return rdma.ring_allgather_matmul_rdma(
-            x, w, axis, return_gathered=return_gathered)
     return cmm.ring_allgather_matmul(x, w, axis,
                                      return_gathered=return_gathered)
 

@@ -11,8 +11,10 @@ exactly as the callsite issued them — not a canonical square weight, so
 wall-clock replay prices the actual matmul.  Fused cells without recorded
 geometry (v1 traces) cannot be replayed; the tuner note-skips them.
 
-This backend runs on whatever devices the process sees (CPU host devices in
-this container).  Its absolute numbers are CPU-flavored; the tuner uses it to
+A cell of world ``w`` runs on the first ``w`` devices the process sees
+(CPU host devices in tests, TPU chips on a chip host), so the sub-axis
+cells of a multi-axis mesh (``p = 2`` on a 2x2 host) are measured on a
+sub-mesh of that size.  Its absolute numbers are CPU-flavored; the tuner uses it to
 validate *orderings* and to exercise the full offline-tuning pipeline, while
 production-scale decisions use ``core.costmodel``.
 """
@@ -25,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro._compat import shard_map
+from jax import shard_map
 
 from repro.core import collectives as C
 from repro.core.cell import OpCell
@@ -38,26 +40,29 @@ MATMUL_OPS = ("allgather_matmul", "matmul_reducescatter", "matmul_accumulate",
               "matmul_reducescatter_2d")
 
 
-@lru_cache(maxsize=1)
-def _mesh() -> Mesh:
-    devs = np.array(jax.devices())
-    return Mesh(devs, (AXIS,))
+def _devices(world: int) -> np.ndarray:
+    devs = jax.devices()
+    if world > len(devs):
+        raise ValueError(f"replay needs {world} devices, have {len(devs)}")
+    return np.array(devs[:world])
+
+
+@lru_cache(maxsize=8)
+def _mesh(p: int | None = None) -> Mesh:
+    """The 1-D replay mesh over the first ``p`` devices (default: all)."""
+    return Mesh(_devices(p or len(jax.devices())), (AXIS,))
 
 
 @lru_cache(maxsize=8)
 def _mesh2(p: int, p2: int) -> Mesh:
-    """The (outer, inner) replay mesh of a 2-D cell; requires the host
-    devices to factor exactly as p x p2."""
-    devs = np.array(jax.devices())
-    if devs.size != p * p2:
-        raise ValueError(
-            f"2-D replay needs {p}x{p2}={p * p2} host devices, "
-            f"have {devs.size}")
-    return Mesh(devs.reshape(p, p2), (AXIS, AXIS2))
+    """The (outer, inner) replay mesh of a 2-D cell over the first
+    ``p * p2`` devices."""
+    return Mesh(_devices(p * p2).reshape(p, p2), (AXIS, AXIS2))
 
 
 def axis_size() -> int:
-    return _mesh().devices.size
+    """Number of devices the process sees: the largest replay world."""
+    return len(jax.devices())
 
 
 def host_cell(op: str, nbytes: int, *, dtype: str = "float32",
@@ -118,11 +123,8 @@ def _compiled(cell: OpCell, impl: str):
         return _compiled_2d(cell, impl)
     if cell.hier:
         return _compiled_hier(cell, impl)
-    mesh = _mesh()
-    p = mesh.devices.size
-    if cell.p != p:
-        raise ValueError(
-            f"measured backend runs at p={p}, not {cell.p}")
+    mesh = _mesh(cell.p)
+    p = cell.p
     fn = C.REGISTRY[cell.op][impl].fn
     shapes = problem_shapes(cell)
     dt = jnp.dtype(cell.dtype if cell.dtype else "float32")
@@ -203,9 +205,10 @@ def _compiled_2d(cell: OpCell, impl: str):
     return jax.jit(sm).lower(x).compile(), x
 
 
-@lru_cache(maxsize=1)
-def _barrier():
-    mesh = _mesh()
+@lru_cache(maxsize=8)
+def _barrier(world: int):
+    """A 1-element psum over the replay world's devices."""
+    mesh = _mesh(world)
 
     def body(x):
         return jax.lax.psum(x, AXIS)
@@ -221,7 +224,7 @@ def sample_latency(cell: OpCell, impl: str, count: int,
                    *, barrier: bool = True) -> list[float]:
     """``count`` barrier-synced wall-clock samples of one cell (s)."""
     fn, x = _compiled(cell, impl)
-    bar, bx = _barrier()
+    bar, bx = _barrier(cell.world())
     # warm one execution so first-run allocation noise is out of the samples
     jax.block_until_ready(fn(x))
     out = []

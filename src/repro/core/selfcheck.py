@@ -1,9 +1,11 @@
 """Multi-device SPMD validation of every mock-up against the numpy oracle.
 
-Run as a subprocess (so the forced host-device count never leaks into the
-parent):
+On the CPU (``JAX_PLATFORMS=cpu``) run it as a subprocess, so the forced
+host-device count never leaks into the parent; on chips it runs in the
+process that holds them (``chip_smoke.py --chips 4``).  It fails when the
+process sees fewer devices than ``--devices``:
 
-    python -m repro.core.selfcheck --devices 8 [--json]
+    JAX_PLATFORMS=cpu python -m repro.core.selfcheck --devices 8 [--json]
 
 Exercises every registered implementation through a REAL ``shard_map`` over a
 multi-device mesh (the vmap semantic tests cover tracing; this covers SPMD
@@ -144,18 +146,39 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
 
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}")
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        # host devices stand in for chips only on the CPU backend
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            f"--xla_force_host_platform_device_count={args.devices}")
 
+    import jax
+
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
+    have = len(jax.devices())
+    if have < args.devices:
+        print(f"selfcheck: --devices {args.devices}, but this process sees "
+              f"{have} {jax.default_backend()} device(s)", file=sys.stderr)
+        return 2
+    # the checks compare with float64 numpy at atol 1e-5: f32 matmuls run
+    # at full f32 precision (a TPU's default is a bf16 pass)
+    with jax.default_matmul_precision("highest"):
+        return _checks(args.devices, args.json)
+
+
+def _checks(P_: int, as_json: bool) -> int:
+    """Every registered impl through a real ``shard_map`` over the first
+    ``P_`` devices (and a 2 x P_/2 mesh); returns the exit code."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro._compat import shard_map
+    from jax import shard_map
 
     from repro.core import collectives as C
 
-    P_ = args.devices
     mesh = Mesh(np.array(jax.devices()[:P_]), ("x",))
     rng = np.random.default_rng(42)
 
@@ -194,7 +217,7 @@ def main(argv=None) -> int:
                          reason=f"tolerance rel={rel:.3g} > {rtol:.3g}")
                 demoted.append(name)
         results[name] = ok
-        if not args.json:
+        if not as_json:
             tag = "OK" if ok else ("DEMOTED" if name in demoted else "FAIL")
             print(f"{name:44s} {tag}")
 
@@ -247,15 +270,17 @@ def main(argv=None) -> int:
         P_, P_ * n, w)
     for nm in C.impl_names("alltoall"):
         check(f"alltoall/{nm}", run(C.REGISTRY["alltoall"][nm].fn, xbf), wanta2a)
+    # non-zero roots (exercise the relative-rank schedules) on any P_
+    r_b, r_g, r_s = 3 % P_, 2 % P_, 5 % P_
     for nm in C.impl_names("bcast"):
-        y = run(C.REGISTRY["bcast"][nm].fn, xf, root=3)
-        check(f"bcast/{nm}", y, np.broadcast_to(x[3], (P_, n, w)))
+        y = run(C.REGISTRY["bcast"][nm].fn, xf, root=r_b)
+        check(f"bcast/{nm}", y, np.broadcast_to(x[r_b], (P_, n, w)))
     for nm in C.impl_names("gather"):
-        y = run(C.REGISTRY["gather"][nm].fn, xf, root=2)
-        check(f"gather/{nm}", y, full, rank=2)
-    wantsc = xb[5].reshape(P_, n, w)
+        y = run(C.REGISTRY["gather"][nm].fn, xf, root=r_g)
+        check(f"gather/{nm}", y, full, rank=r_g)
+    wantsc = xb[r_s].reshape(P_, n, w)
     for nm in C.impl_names("scatter"):
-        check(f"scatter/{nm}", run(C.REGISTRY["scatter"][nm].fn, xbf, root=5),
+        check(f"scatter/{nm}", run(C.REGISTRY["scatter"][nm].fn, xbf, root=r_s),
               wantsc)
     for nm in C.impl_names("reduce"):
         y = run(C.REGISTRY["reduce"][nm].fn, xf, root=1, chunk=2)
@@ -353,7 +378,7 @@ def main(argv=None) -> int:
         check(f"matmul_reducescatter_2d/{nm}/xpose", yt, want_2dt)
 
     fails = [k for k, v in results.items() if not v and k not in demoted]
-    if args.json:
+    if as_json:
         print(json.dumps({"devices": P_, "total": len(results),
                           "failures": fails, "demoted": demoted}))
     else:

@@ -118,6 +118,10 @@ class CostModelBackend:
 class MeasuredBackend:
     """Wall-clock on host devices; NREP via the paper's estimator.
 
+    Measures at one world size: ``axis_size`` devices (the first ones the
+    process sees), by default all of them.  A 2x2 host measures its
+    ``p = 2`` sub-axis cells with ``axis_size=2``.
+
     Replays each cell's RECORDED problem — for fused collective-matmul
     cells that is the callsite's actual GEMM ``(dtype, mm_k, mm_m, mm_n)``,
     not a canonical square weight.  Fused cells without geometry (v1
@@ -127,7 +131,12 @@ class MeasuredBackend:
     name = "measured"
 
     def __init__(self, *, rse_1byte: float = 0.05, rse_large: float = 0.10,
-                 K: int = 5, max_nrep: int = 50):
+                 K: int = 5, max_nrep: int = 50,
+                 axis_size: int | None = None):
+        self.axis_size = axis_size or measure.axis_size()
+        if self.axis_size > measure.axis_size():
+            raise ValueError(f"axis_size {self.axis_size} > the "
+                             f"{measure.axis_size()} devices present")
         self.rse_1byte = rse_1byte
         self.rse_large = rse_large
         self.K = K
@@ -137,9 +146,9 @@ class MeasuredBackend:
 
     @property
     def supported_axis_size(self) -> int:
-        """Wall clock only exists at the axis size the host devices form;
+        """Wall clock exists only at the world the backend measures on;
         the trace-replay tuner skips (and notes) every other cell."""
-        return measure.axis_size()
+        return self.axis_size
 
     @staticmethod
     def _measurable(cell: OpCell) -> bool:
@@ -170,9 +179,9 @@ class MeasuredBackend:
         return self._nrep[key]
 
     def latency(self, cell: OpCell, impl: str) -> float:
-        if cell.world() != measure.axis_size():
+        if cell.world() != self.axis_size:
             raise ValueError(
-                f"measured backend runs at world={measure.axis_size()}, "
+                f"measured backend runs at world={self.axis_size}, "
                 f"not {cell.world()} (cell p={cell.p}, p2={cell.p2})")
         if not self._measurable(cell):
             # fused op without recorded geometry: nothing faithful to replay
@@ -381,9 +390,9 @@ def tune_trace(trace, backend=None, *, min_win: float = 0.10,
     replay": each recorded cell is re-executed on the host devices with its
     RECORDED problem — fused collective-matmul cells replay the callsite's
     actual GEMM ``(dtype, mm_k, mm_m, mm_n)`` — and timed (serving profiles
-    from wall clock, not the model).  Cells whose ``p`` differs from
-    ``measure.axis_size()`` cannot be replayed and are skipped with a note;
-    so are fused cells without recorded geometry (v1 traces).
+    from wall clock, not the model).  Cells whose world differs from the
+    backend's ``supported_axis_size`` are not replayed and are skipped
+    with a note; so are fused cells without recorded geometry (v1 traces).
 
     Emitted profiles are keyed like the cells: fused cells produce one
     geometry profile per ``(op, p, Geom)`` — the store's nearest-cell
@@ -402,6 +411,9 @@ def tune_trace(trace, backend=None, *, min_win: float = 0.10,
 
     for ph in trace.phases():
         picks: dict[tuple, list[tuple[int, str]]] = {}
+        # weighted latency per impl of each profile range: cells that one
+        # range cannot tell apart (one size in two dtypes) decide together
+        groups: dict[tuple, dict[str, float]] = {}
         t_d = t_t = 0.0
         for cell, weight in sorted(trace.cells(phase=ph).items()):
             op, p, nbytes = cell.op, cell.p, cell.nbytes
@@ -435,15 +447,23 @@ def tune_trace(trace, backend=None, *, min_win: float = 0.10,
                                  "unmeasurable; cell skipped")
                 continue
             t_d += weight * t_def
-            cands = {k: v for k, v in lats.items() if k != "default"}
+            key = (op, p, cell.geom(), cell.profile_tier(), nbytes)
+            tot = {k: weight * v for k, v in lats.items()}
+            if key in groups:
+                tot = {k: v + groups[key][k] for k, v in tot.items()
+                       if k in groups[key]}
+            groups[key] = tot
+
+        for (op, p, geom, tier, nbytes), tot in groups.items():
+            t_def = tot["default"]
+            cands = {k: v for k, v in tot.items() if k != "default"}
             best = min(cands, key=cands.get) if cands else None
             if best is not None and cands[best] < t_def * (1.0 - min_win):
-                picks.setdefault(
-                    (op, p, cell.geom(), cell.profile_tier()), []).append(
+                picks.setdefault((op, p, geom, tier), []).append(
                     (nbytes, best))
-                t_t += weight * cands[best]
+                t_t += cands[best]
             else:
-                t_t += weight * t_def
+                t_t += t_def
 
         for (op, p, geom, tier), pk in sorted(
                 picks.items(), key=lambda kv: (kv[0][0], kv[0][1],

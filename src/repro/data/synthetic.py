@@ -8,6 +8,8 @@ loss decreases during the example runs, unlike uniform noise).
 """
 from __future__ import annotations
 
+import zlib
+
 import numpy as np
 
 import jax
@@ -42,7 +44,9 @@ def make_batch(cfg: ModelConfig, batch: int, seq: int, step: int,
     """Host-side numpy batch (the given shard slice of the global batch)."""
     assert batch % n_shards == 0
     b_loc = batch // n_shards
-    rng = np.random.default_rng((hash(cfg.name) & 0xFFFF, step, shard))
+    # crc32, not hash(): str hashes are salted per process
+    rng = np.random.default_rng((zlib.crc32(cfg.name.encode()) & 0xFFFF,
+                                 step, shard))
     specs = batch_specs(cfg, batch, seq)
     t_shape = (b_loc,) + specs["tokens"].shape[1:]
     # Zipf-distributed ids with per-sequence offset => learnable structure

@@ -15,5 +15,6 @@ from repro.dist.ops import (allgather_matmul, col_matmul,  # noqa: F401
                             ep_alltoall, fsdp_gather, fsdp_matmul,
                             matmul_accumulate, matmul_reducescatter,
                             matmul_reducescatter_2d, row_matmul,
-                            tp_allgather, tp_allreduce, tp_copy,
+                            tp_allgather_split,
+                            tp_allreduce, tp_copy,
                             tp_psum_grad, tp_reducescatter)

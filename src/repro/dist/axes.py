@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import dataclasses
 
-from jax import core as _core
 from jax import lax
 
 from repro.core._axis import axis_size
@@ -43,19 +42,8 @@ def has_axis(axis_name: str | None) -> bool:
     """True iff ``axis_name`` is bound in the current trace (static)."""
     if not axis_name:
         return False
-    frame = getattr(_core, "axis_frame", None)
-    if frame is not None:
-        try:
-            frame(axis_name)
-            return True
-        except NameError:
-            return False
-    # newer jax: no core.axis_frame — probe by resolving the axis size
     try:
-        if hasattr(lax, "axis_size"):
-            lax.axis_size(axis_name)
-        else:
-            axis_size(axis_name)
+        lax.axis_size(axis_name)
         return True
     except (NameError, KeyError, ValueError, TypeError):
         return False

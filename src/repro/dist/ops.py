@@ -15,7 +15,7 @@ Gradient pairing (per-shard semantics; axis size ``p``):
 op                   forward collective         backward collective
 ===================  =========================  ==========================
 fsdp_gather          api.allgather (data)       api.reducescatter (data)
-tp_allgather         api.allgather (model)      api.reducescatter (model)
+tp_allgather_split   api.allgather (model)      own block (Megatron gather)
 tp_reducescatter     api.reducescatter          api.allgather
 tp_allreduce         api.allreduce              identity (Megatron "g")
 tp_copy              identity                   api.allreduce (Megatron "f")
@@ -51,6 +51,10 @@ to the single all-reduce when the row count does not divide the axis);
 ``row_matmul(..., fsdp_dim=1)`` fuses the DATA-axis weight gather of a
 row-parallel weight into the matmul itself (the fsdp_gather→matmul sites in
 models/), keeping the model-axis reduction a classic tunable all-reduce.
+
+``tp_allgather_split`` is for a gathered value that every shard then uses
+alike: its cotangent is the same on every shard, so each keeps its own
+block and the backward needs no collective.
 
 ``tp_copy`` marks a replicated ACTIVATION entering a model-sharded region
 (its cotangents arrive partial per shard and must be summed);
@@ -134,11 +138,30 @@ def fsdp_gather(x, dim: int = 0, axis: str = AXES.data):
     return _gather(dim, axis, x)
 
 
-def tp_allgather(x, dim: int, axis: str = AXES.model):
-    """All-gather a model-sharded activation along ``dim``."""
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _gather_split(dim: int, axis: str, x):
+    return _moved(lambda a: api.allgather(a, axis), x, dim)
+
+
+def _gather_split_fwd(dim, axis, x):
+    return _gather_split(dim, axis, x), None
+
+
+def _gather_split_bwd(dim, axis, _, g):
+    n = g.shape[dim] // axis_size(axis)
+    return (jax.lax.dynamic_slice_in_dim(g, axis_index(axis) * n, n, dim),)
+
+
+_gather_split.defvjp(_gather_split_fwd, _gather_split_bwd)
+
+
+def tp_allgather_split(x, dim: int, axis: str = AXES.model):
+    """All-gather a model-sharded activation that every shard then uses
+    alike; the backward keeps this shard's block of the cotangent, which
+    is the same on every shard, with no collective."""
     if not has_axis(axis):
         return x
-    return _gather(dim, axis, x)
+    return _gather_split(dim % x.ndim, axis, x)
 
 
 def tp_reducescatter(x, dim: int = 0, axis: str = AXES.model):

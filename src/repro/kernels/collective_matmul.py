@@ -60,9 +60,9 @@ Three execution tiers:
 3. **RDMA ring kernel** (``collective_matmul_rdma.ring_allgather_matmul_
    rdma``): a single Pallas kernel that drives ``make_async_remote_copy``
    sends itself — the full latency-hiding schedule with no XLA scheduling
-   dependence.  TPU-only and kept in its own module; the ``fused_ring``
-   dispatcher impl performs the backend check (``on_tpu``) and only
-   imports the RDMA module on TPU, so CPU CI never loads that path.
+   dependence.  TPU-only, kept in its own module and NOT on the dispatch
+   path: ``fused_ring`` is the tier-1 ring (with the tier-2 chunk matmul on
+   TPU) on every backend.
 """
 from __future__ import annotations
 
@@ -88,8 +88,7 @@ def _cdiv(a: int, b: int) -> int:
 
 
 def on_tpu() -> bool:
-    """Backend check gating the TPU-only execution tiers (RDMA ring,
-    non-interpret Pallas)."""
+    """Backend check gating the compiled (non-interpret) Pallas tier."""
     try:
         return jax.default_backend() == "tpu"
     except Exception:  # pragma: no cover - backend probing never fatal
@@ -195,7 +194,7 @@ def ring_allgather_matmul(x, w, axis: str, *, return_gathered: bool = False,
     for s in range(p):
         # issue the transfer of the NEXT chunk before consuming this one:
         # the matmul below has no data dependence on it, so the scheduler
-        # (or the RDMA kernel on TPU) can run both concurrently.
+        # can run both concurrently.
         nxt = lax.ppermute(cur, axis, ring_perm(p, 1)) if s < p - 1 else None
         src = (idx - s) % p                # originating rank of `cur`
         blk = _local_mm(cur, w, mm).astype(out_dtype)
@@ -512,17 +511,3 @@ def ring_matmul_accumulate_wire(x, w, axis: str, *, wire_dtype: str = "int8",
             cur = Qz.dequantize(q, sc, w.dtype)
     out = acc.astype(out_dtype)
     return (out, gath) if return_gathered else out
-
-
-# ---------------------------------------------------------------------------
-# tier 3 lives in kernels/collective_matmul_rdma.py (TPU-only module); keep
-# the historical import path working without loading it on CPU.
-# ---------------------------------------------------------------------------
-
-
-def __getattr__(name: str):
-    if name == "ring_allgather_matmul_rdma":
-        from repro.kernels.collective_matmul_rdma import \
-            ring_allgather_matmul_rdma
-        return ring_allgather_matmul_rdma
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

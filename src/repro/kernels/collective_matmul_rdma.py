@@ -5,11 +5,14 @@ A Pallas kernel that drives ``make_async_remote_copy`` sends itself
 (double-buffered comm scratch, per-slot DMA semaphores, neighbour barrier) —
 the full latency-hiding schedule with no XLA scheduling dependence.
 
-The REAL kernel (``ring_allgather_matmul_rdma``) stays TPU-only: the
-``fused_ring`` dispatcher impl (core/collectives.py) performs the backend
-check (``on_tpu``) and only calls it on TPU — ``make_async_remote_copy``
-has no host interpret path across shard_map devices.  The module itself is
-now importable anywhere so CPU CI can exercise the ring's BLOCK logic:
+The REAL kernel (``ring_allgather_matmul_rdma``) is TPU-only —
+``make_async_remote_copy`` has no host interpret path across shard_map
+devices — and is not on the dispatch path: ``fused_ring``
+(core/collectives.py) is the ppermute ring on every backend.  It keeps
+``x``, ``w``, the output and the gathered copy whole in VMEM, so it only
+compiles at sizes that fit there (tests/test_tpu_compile.py compiles one).
+The module is importable anywhere so CPU CI can exercise the ring's BLOCK
+logic:
 
 * ``ring_step_src`` / ``ring_step_slots`` — the per-step rank/double-buffer
   indexing, shared verbatim by the RDMA kernel, the interpret tier, and
@@ -38,11 +41,6 @@ from repro.core._axis import axis_size
 
 __all__ = ["ring_allgather_matmul_rdma", "ring_allgather_matmul_blocks",
            "ring_step_src", "ring_step_slots", "ring_schedule"]
-
-# jax 0.4.x names this TPUCompilerParams; new jax uses CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 
 def ring_step_src(my, s, p: int):
     """Originating rank of the chunk resident at ring step ``s`` on rank
@@ -103,9 +101,9 @@ def _agmm_rdma_kernel(x_ref, w_ref, o_ref, gath_ref, comm_buf, send_sem,
     def _seed():
         # neighbour barrier so nobody RDMAs into a peer still setting up
         bar = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(bar, inc=1, device_id=(left,),
+        pltpu.semaphore_signal(bar, inc=1, device_id=left,
                                device_id_type=pltpu.DeviceIdType.LOGICAL)
-        pltpu.semaphore_signal(bar, inc=1, device_id=(right,),
+        pltpu.semaphore_signal(bar, inc=1, device_id=right,
                                device_id_type=pltpu.DeviceIdType.LOGICAL)
         pltpu.semaphore_wait(bar, 2)
         comm_buf[0] = x_ref[...]
@@ -117,16 +115,17 @@ def _agmm_rdma_kernel(x_ref, w_ref, o_ref, gath_ref, comm_buf, send_sem,
         # right neighbour finished reading its slot `nxt` at its step s-1
         pltpu.semaphore_wait(credit_sem, 1)
 
+    rdma = pltpu.make_async_remote_copy(
+        src_ref=comm_buf.at[slot],
+        dst_ref=comm_buf.at[nxt],
+        send_sem=send_sem.at[slot],
+        recv_sem=recv_sem.at[nxt],
+        device_id=right,
+        device_id_type=pltpu.DeviceIdType.LOGICAL,
+    )
+
     @pl.when(s < p - 1)
     def _send():
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=comm_buf.at[slot],
-            dst_ref=comm_buf.at[nxt],
-            send_sem=send_sem.at[slot],
-            recv_sem=recv_sem.at[nxt],
-            device_id=(right,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
         rdma.start()
 
     # matmul the chunk we hold while the RDMA is in flight
@@ -141,8 +140,10 @@ def _agmm_rdma_kernel(x_ref, w_ref, o_ref, gath_ref, comm_buf, send_sem,
 
     @pl.when(s < p - 1)
     def _wait():
-        pltpu.semaphore_wait(send_sem.at[slot], 1)
-        pltpu.semaphore_wait(recv_sem.at[nxt], 1)
+        # our send out of `slot` left, and the left neighbour's landed
+        # in our `nxt`
+        rdma.wait_send()
+        rdma.wait_recv()
 
     @pl.when(s < p - 2)
     def _grant():
@@ -150,7 +151,7 @@ def _agmm_rdma_kernel(x_ref, w_ref, o_ref, gath_ref, comm_buf, send_sem,
         # from it delivered): the left neighbour may target it again with
         # its step-s+1 send.  Credits exactly balance the waits above, so
         # the semaphore drains to zero by kernel exit.
-        pltpu.semaphore_signal(credit_sem, inc=1, device_id=(left,),
+        pltpu.semaphore_signal(credit_sem, inc=1, device_id=left,
                                device_id_type=pltpu.DeviceIdType.LOGICAL)
 
 
@@ -185,7 +186,7 @@ def ring_allgather_matmul_rdma(x, w, axis: str, *,
             pltpu.SemaphoreType.REGULAR,           # buffer-reuse credits
             pltpu.VMEM((n, m), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
     )(x, w)
     return (out, gath) if return_gathered else out
@@ -207,17 +208,13 @@ def _agmm_block_kernel(xall_ref, w_ref, o_ref, gath_ref, comm_buf, acc_scr,
 
     @pl.when(s == 0)
     def _seed():
-        comm_buf[0] = pl.load(
-            xall_ref, (pl.ds(my, 1), slice(None), slice(None)))[0]
+        comm_buf[0] = xall_ref[my]
 
     @pl.when(s < p - 1)
     def _send():
         # the step-s RDMA targets slot `nxt` with the chunk this rank will
         # consume at step s+1 (originated by ring_step_src(my, s+1, p))
-        arriving = pl.load(
-            xall_ref, (pl.ds(ring_step_src(my, s + 1, p), 1),
-                       slice(None), slice(None)))[0]
-        comm_buf[nxt] = arriving
+        comm_buf[nxt] = xall_ref[ring_step_src(my, s + 1, p)]
 
     src = ring_step_src(my, s, p)
     n = xall_ref.shape[1]

@@ -33,8 +33,10 @@ Execution tiers (same split as kernels/collective_matmul.py):
    surrounding ring step.
 2. ``quant_pack``/``dequant_unpack`` — the per-block Pallas kernels in the
    kernels/pack.py style (grid over blocks, one scale per grid step), the
-   TPU tier; exercised on CPU via ``interpret=True``.  On TPU the natural
-   tile floor for 8-bit lanes is (32, 128); the kernels pad short blocks.
+   TPU tier; exercised on CPU via ``interpret=True``.  The scales live
+   whole in SMEM as a flat ``[nblocks]`` vector (one scalar store/load per
+   grid step) — a ``(1, 1)`` VMEM block breaks the (8, 128) tiling rule.
+   SMEM is small, so one call handles payloads up to a few thousand blocks.
 """
 from __future__ import annotations
 
@@ -43,6 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["WIRE_DTYPES", "WIRE_ITEMSIZE", "QMAX", "BLOCK_ROWS", "BASE_TOL",
            "wire_tol", "quantize", "dequantize", "wire_roundtrip",
@@ -143,7 +146,7 @@ def wire_roundtrip(x, wire_dtype: str = "int8", *,
 def _quant_kernel(x_ref, q_ref, s_ref, *, qmax: float, wire_dtype: str):
     xb = x_ref[...].astype(jnp.float32)
     scale = jnp.maximum(jnp.max(jnp.abs(xb)), _SCALE_FLOOR) / qmax
-    s_ref[0, 0] = scale
+    s_ref[pl.program_id(0)] = scale
     if wire_dtype == "int8":
         q_ref[...] = jnp.clip(jnp.round(xb / scale), -qmax, qmax).astype(
             jnp.int8)
@@ -169,18 +172,18 @@ def quant_pack(x, *, wire_dtype: str = "int8",
         grid=(nb,),
         in_specs=[pl.BlockSpec((block_rows, d), lambda j: (j, 0))],
         out_specs=[pl.BlockSpec((block_rows, d), lambda j: (j, 0)),
-                   pl.BlockSpec((1, 1), lambda j: (j, 0))],
+                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_shape=[jax.ShapeDtypeStruct((nb * block_rows, d),
                                         jnp.dtype(wire_dtype)),
-                   jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((nb,), jnp.float32)],
         interpret=interpret,
     )(xp)
-    return q[:n], scales
+    return q[:n], scales.reshape(nb, 1)
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
-    o_ref[...] = (q_ref[...].astype(jnp.float32) * s_ref[0, 0]).astype(
-        o_ref.dtype)
+    o_ref[...] = (q_ref[...].astype(jnp.float32)
+                  * s_ref[pl.program_id(0)]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -196,10 +199,10 @@ def dequant_unpack(q, scales, *, out_dtype=jnp.float32,
         _dequant_kernel,
         grid=(nb,),
         in_specs=[pl.BlockSpec((block_rows, d), lambda j: (j, 0)),
-                  pl.BlockSpec((1, 1), lambda j: (j, 0))],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((block_rows, d), lambda j: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * block_rows, d),
                                        jnp.dtype(out_dtype)),
         interpret=interpret,
-    )(qp, scales)
+    )(qp, scales.reshape(nb))
     return out[:n]

@@ -41,7 +41,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool,
              donate: bool = False, unroll: bool = False,
              tag: str = "") -> dict:
     import jax
-    from repro._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.analysis.hlo import collective_bytes, program_costs

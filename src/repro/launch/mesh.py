@@ -7,22 +7,28 @@ pure DP over DCN (hierarchical gradient sync — see train/trainer.py).
 """
 from __future__ import annotations
 
-import jax
+import math
 
-from repro._compat import auto_axis_types, make_mesh, mesh_with_axis_types
+import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    import numpy as np
-    devs = jax.devices()
-    n = 1
-    for s in shape:
-        n *= s
-    return mesh_with_axis_types(np.asarray(devs[:n]).reshape(shape), axes)
+    devs = jax.devices()[:math.prod(shape)]
+    return Mesh(np.asarray(devs).reshape(shape), axes,
+                axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Small mesh over host devices (tests / measured tuning)."""
-    return make_mesh(shape, axes, axis_types=auto_axis_types(len(axes)))
+    """Mesh over the first ``prod(shape)`` local devices (tests, measured
+    tuning, ``launch/train.py --mesh dxt``).  Fails when the process sees
+    fewer devices than the mesh needs."""
+    n, have = math.prod(shape), len(jax.devices())
+    if have < n:
+        raise ValueError(f"mesh {shape} needs {n} devices, this process "
+                         f"sees {have}")
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:n])

@@ -27,7 +27,7 @@ import contextlib
 
 import jax
 import jax.numpy as jnp
-from repro._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import api

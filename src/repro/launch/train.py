@@ -7,14 +7,36 @@ async checkpointing, straggler watchdog, and crash-resume.
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-3b \
         --smoke --steps 50 --profile-dir results/profiles_v5e
 
-On a real pod, drop --smoke and pass --mesh 16x16 / --mesh 2x16x16 (this
-container has one CPU device, so full-size runs are for TPU hosts; the
-same code path is exercised at 1-device and 8-device scale by the tests).
+At published widths on one TPU v5e chip, cut in depth only, with the
+``shard_map`` step and tuned dispatch traced exactly as on a pod:
+
+    PYTHONPATH=src python -m repro.launch.train --arch rwkv6-3b \
+        --layers 4 --mesh 1x1 --global-batch 2 --seq 1024 --steps 5
+
+On a pod pass ``--mesh 16x16`` / ``--mesh 2x16x16``; ``--mesh DxT`` builds a
+(data, model) mesh over the first D*T local devices.  Without ``--mesh``
+the step is a plain ``jit`` and every ``dist.ops`` collective degrades to
+its local meaning, so the tuner sees no traffic.
 """
 from __future__ import annotations
 
 import argparse
+import collections
+import dataclasses
 import time
+
+
+def dispatch_summary(record) -> str:
+    """One line on the collectives the traced step dispatched: how many
+    sites, and the impl at each site that did not take the default."""
+    picked = collections.Counter((r.op, r.impl) for r in record
+                                 if r.impl != "default")
+    line = (f"dispatch: {len(record)} sites traced, "
+            f"{sum(picked.values())} not default")
+    if picked:
+        line += ": " + ", ".join(f"{op}={impl} x{n}"
+                                 for (op, impl), n in sorted(picked.items()))
+    return line
 
 
 def main(argv=None) -> int:
@@ -22,6 +44,9 @@ def main(argv=None) -> int:
     ap.add_argument("--arch", default="llama3.2-3b")
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-sized)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep only the first N layers (depth cut; every"
+                         " width stays as published); 0 = all")
     ap.add_argument("--mesh", default="",
                     help="'16x16' | '2x16x16' | 'dxt' over host devices;"
                          " empty = single device")
@@ -44,6 +69,9 @@ def main(argv=None) -> int:
 
     import jax
 
+    from repro.jax_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.ckpt import AsyncCheckpointer, checkpoint as ck
     from repro.configs import get_config
     from repro.core.api import parse_module_spec
@@ -56,6 +84,8 @@ def main(argv=None) -> int:
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
 
     mesh = None
     if args.mesh == "16x16":
@@ -73,10 +103,11 @@ def main(argv=None) -> int:
               f"phases={sorted(phase_stores)}")
     force = parse_module_spec(args.force) if args.force else None
 
+    record: list = []
     tr = Trainer(cfg, mesh=mesh, n_micro=args.n_micro,
                  compress=args.compress, profiles=profiles,
                  phase_profiles=phase_stores or None, force=force,
-                 base_lr=args.lr, warmup=args.warmup)
+                 base_lr=args.lr, warmup=args.warmup, record=record)
     params, opt = tr.init(0)
     start = ck.latest_step(args.ckpt_dir) or 0
     if start:
@@ -87,20 +118,36 @@ def main(argv=None) -> int:
 
     acp = AsyncCheckpointer(args.ckpt_dir)
     wd = StepWatchdog(ratio=4.0)
-    t0 = time.time()
-    for i in range(start, args.steps):
-        wd.start_step()
-        batch = tr.put_batch(make_batch(cfg, args.global_batch, args.seq, i))
-        params, opt, m = tr.step(params, opt, batch, i)
+
+    def report(i, m):
+        # a step's time runs from the previous step's result to its own, so
+        # the host prepares step i + 1 while the device runs step i
+        jax.block_until_ready(m["loss"])
         straggler = wd.end_step()
+        wd.start_step()
         if i % args.log_every == 0 or straggler:
             note = "  [STRAGGLER]" if straggler else ""
             print(f"step {i:5d}  loss {float(m['loss']):.4f}  "
                   f"gnorm {float(m['grad_norm']):.2f}  "
                   f"lr {float(m['lr']):.2e}  "
-                  f"{wd.median*1e3:.0f} ms/step{note}", flush=True)
+                  f"{wd.times[-1]*1e3:.1f} ms  "
+                  f"(median {wd.median*1e3:.1f} ms){note}", flush=True)
+
+    t0 = time.time()
+    wd.start_step()
+    pending = None
+    for i in range(start, args.steps):
+        batch = tr.put_batch(make_batch(cfg, args.global_batch, args.seq, i))
+        params, opt, m = tr.step(params, opt, batch, i)
+        if i == start:
+            print(dispatch_summary(record), flush=True)
+        if pending is not None:
+            report(*pending)
+        pending = (i, m)
         if (i + 1) % args.ckpt_every == 0:
             acp.save(i + 1, {"params": params, "opt": opt})
+    if pending is not None:
+        report(*pending)
     acp.wait()
     ck.save(args.ckpt_dir, args.steps, {"params": params, "opt": opt})
     dt = time.time() - t0

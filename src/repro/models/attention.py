@@ -6,7 +6,8 @@ Head sharding contract (TP degree ``t``):
 * kv heads: if ``kv % t == 0`` the kv projections are model-sharded like q;
   otherwise (kv < t, e.g. gemma MQA) kv projections are REPLICATED, every
   shard computes all kv heads, and ``tp_psum_grad`` sums the partial weight
-  grads.  The per-shard q-head block picks its kv group by index.
+  grads, as ``tp_copy`` on their input sums its partial grads.  The
+  per-shard q-head block picks its kv group by index.
 
 Cache modes:
 
@@ -309,8 +310,9 @@ def attention(p: dict, cfg: ModelConfig, x, *, pos, kind: str = "causal",
             k = ops.col_matmul(x, p["w_k"], fsdp_dim=0)
             v = ops.col_matmul(x, p["w_v"], fsdp_dim=0)
         else:
-            k = ops.matmul_accumulate(x, ops.tp_psum_grad(p["w_k"]))
-            v = ops.matmul_accumulate(x, ops.tp_psum_grad(p["w_v"]))
+            xkv = ops.tp_copy(x)
+            k = ops.matmul_accumulate(xkv, ops.tp_psum_grad(p["w_k"]))
+            v = ops.matmul_accumulate(xkv, ops.tp_psum_grad(p["w_v"]))
         n_kv_loc = (cfg.n_kv_heads // tp) if kv_sharded else cfg.n_kv_heads
         k = k.reshape(*x.shape[:-1], n_kv_loc, hd)
         v = v.reshape(*x.shape[:-1], n_kv_loc, hd)

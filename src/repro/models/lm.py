@@ -310,8 +310,9 @@ def _cross_kv(p, cfg: ModelConfig, enc_out):
         k = ops.col_matmul(enc_out, p["w_k"], fsdp_dim=0)
         v = ops.col_matmul(enc_out, p["w_v"], fsdp_dim=0)
     else:
-        k = ops.matmul_accumulate(enc_out, ops.tp_psum_grad(p["w_k"]))
-        v = ops.matmul_accumulate(enc_out, ops.tp_psum_grad(p["w_v"]))
+        enc_kv = ops.tp_copy(enc_out)
+        k = ops.matmul_accumulate(enc_kv, ops.tp_psum_grad(p["w_k"]))
+        v = ops.matmul_accumulate(enc_kv, ops.tp_psum_grad(p["w_v"]))
     n_loc = (cfg.n_kv_heads // tp) if kv_sharded else cfg.n_kv_heads
     k = k.reshape(*enc_out.shape[:-1], n_loc, hd)
     v = v.reshape(*enc_out.shape[:-1], n_loc, hd)

@@ -17,6 +17,8 @@ Shared experts (DeepSeek) run as a dense TP MLP on every token.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -53,6 +55,22 @@ def _capacity(n_tokens: int, cfg: ModelConfig) -> int:
     m = cfg.moe
     c = int(n_tokens * m.top_k * m.capacity_factor / m.n_experts)
     return max(4, -(-c // 4) * 4)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _one_copy_grad(copies: int, w):
+    return w
+
+
+def _one_copy_grad_fwd(copies, w):
+    return w, None
+
+
+def _one_copy_grad_bwd(copies, _, g):
+    return (g / copies,)
+
+
+_one_copy_grad.defvjp(_one_copy_grad_fwd, _one_copy_grad_bwd)
 
 
 def moe_block(p: dict, cfg: ModelConfig, x) -> tuple[jax.Array, jax.Array]:
@@ -127,9 +145,11 @@ def moe_block(p: dict, cfg: ModelConfig, x) -> tuple[jax.Array, jax.Array]:
     buf = buf.reshape(e_loc, tp * cap_buf, d)
 
     # --- expert FFN ----------------------------------------------------------
-    w_in = ops.fsdp_gather(p["w_in"], 1)                         # [Eloc, D, F]
-    w_gate = ops.fsdp_gather(p["w_gate"], 1)
-    w_out = ops.fsdp_gather(p["w_out"], 2)                       # [Eloc, F, D]
+    # every model shard sends the same tokens, so each expert sees each
+    # token tp times: its weight grads count one copy
+    w_in = _one_copy_grad(tp, ops.fsdp_gather(p["w_in"], 1))     # [Eloc, D, F]
+    w_gate = _one_copy_grad(tp, ops.fsdp_gather(p["w_gate"], 1))
+    w_out = _one_copy_grad(tp, ops.fsdp_gather(p["w_out"], 2))   # [Eloc, F, D]
     h = jnp.einsum("ecd,edf->ecf", buf, w_in)
     g = jnp.einsum("ecd,edf->ecf", buf, w_gate)
     y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * h, w_out)

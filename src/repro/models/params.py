@@ -18,8 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro._compat import tree_flatten_with_path
-from repro.dist.axes import axis_size_or_1
+from repro.dist.axes import axis_size_or_1, has_axis
 
 Tree = dict[str, Any]   # nested dict of ParamSpec
 
@@ -93,17 +92,30 @@ def _init_leaf(spec: ParamSpec, key, sizes: dict[str, int]):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dt)
 
 
-def init_tree(tree: Tree, key, *, fold: int = 0):
-    """Initialize shard-local params.  Call INSIDE shard_map; ``fold`` is a
-    per-shard fold (data_idx * tp + model_idx) so different shards hold
-    different random slices, while pods replicate (fold excludes the pod
-    index)."""
+def _shard_fold(spec: ParamSpec, sizes: dict[str, int]):
+    """This shard's index among the shards of ``spec``: the indices of the
+    bound axes the leaf is split over, row-major.  Replicas along every
+    other axis share it, so a replicated leaf starts equal on all of
+    them."""
+    fold = 0
+    for ax in sizes:
+        if ax in spec.dims and has_axis(ax):
+            fold = fold * sizes[ax] + jax.lax.axis_index(ax)
+    return fold
+
+
+def init_tree(tree: Tree, key):
+    """Initialize shard-local params.  Call INSIDE shard_map (or outside it
+    for unsharded params): each leaf's key is folded with its own shard
+    index, so different shards hold different random slices while
+    replicas, pods included, hold the same values."""
     sizes = {"model": axis_size_or_1("model"),
              "data": axis_size_or_1("data")}
-    flat, treedef = tree_flatten_with_path(
+    flat, treedef = jax.tree.flatten_with_path(
         tree, is_leaf=lambda x: isinstance(x, ParamSpec))
     leaves = []
     for i, (path, spec) in enumerate(flat):
-        k = jax.random.fold_in(jax.random.fold_in(key, i), fold)
+        k = jax.random.fold_in(jax.random.fold_in(key, i),
+                               _shard_fold(spec, sizes))
         leaves.append(_init_leaf(spec, k, sizes))
     return jax.tree.unflatten(treedef, leaves)

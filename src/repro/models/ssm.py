@@ -2,7 +2,11 @@
 
 TP contract: SSM heads are sharded over the model axis (RWKV6 heads padded
 up to a multiple of tp).  B/C (mamba) and the decay-LoRA down-projection
-(rwkv) are replicated with ``tp_psum_grad`` markers.
+(rwkv) are replicated.  Mamba's B/C feed the sharded heads, so their
+weight grads are partial per shard and carry ``tp_psum_grad`` markers, and
+their input is marked ``tp_copy``.  RWKV's decay LoRA feeds ``col_matmul``,
+whose backward already sums the input grad over the shards, so it needs
+no marker.
 
 Reference semantics here are pure JAX:
 * mamba2 — chunked SSD (scalar per-head decay ⇒ the [L, L] pairwise decay
@@ -124,7 +128,7 @@ def rwkv_block(p: dict, cfg: ModelConfig, x, *, state=None):
     v = ops.col_matmul(xv, p["w_v"], fsdp_dim=0)
     g = ops.col_matmul(xg, p["w_g"], fsdp_dim=0)
     # data-dependent decay (the Finch headline feature)
-    low = jnp.tanh(ops.matmul_accumulate(xw, ops.tp_psum_grad(p["wA"])))
+    low = jnp.tanh(ops.matmul_accumulate(xw, p["wA"]))
     dec_raw = p["w0"].astype(f32) + ops.col_matmul(
         low, p["wB"]).astype(f32)
     w = jnp.exp(-jnp.exp(dec_raw))                   # (0,1), per channel
@@ -158,7 +162,7 @@ def rwkv_block(p: dict, cfg: ModelConfig, x, *, state=None):
     kk = jnp.square(jax.nn.relu(kk))
     cv = ops.row_matmul(kk, p["w_cv"], fsdp_dim=1)
     r_loc = ops.col_matmul(xcr, p["w_cr"], fsdp_dim=0)
-    r_full = ops.tp_allgather(r_loc, r_loc.ndim - 1)
+    r_full = ops.tp_allgather_split(r_loc, r_loc.ndim - 1)
     y = jax.nn.sigmoid(r_full) * cv
     out = x + y
 
@@ -282,7 +286,7 @@ def mamba_block(p: dict, cfg: ModelConfig, x, *, state=None):
     xn = rms_norm(x, p["ln"], cfg.norm_eps)
     z = ops.col_matmul(xn, p["w_in_z"], fsdp_dim=0)
     xin = ops.col_matmul(xn, p["w_in_x"], fsdp_dim=0)
-    bc = ops.matmul_accumulate(xn, ops.tp_psum_grad(p["w_bc"]))
+    bc = ops.matmul_accumulate(ops.tp_copy(xn), ops.tp_psum_grad(p["w_bc"]))
     dt_raw = ops.col_matmul(xn, p["w_dt"], fsdp_dim=0)
 
     conv_x_w = p["conv_x"]

@@ -71,6 +71,15 @@ def _map_with_specs(fn, tree, spec_tree):
                                         zip(flat_t, flat_s)])
 
 
+def _replicas(spec: ParamSpec) -> int:
+    """How many devices of the bound axes hold the same shard of a leaf."""
+    n = 1
+    for ax in (AXES.data, AXES.model, AXES.pod):
+        if ax not in spec.dims:
+            n *= axis_size_or_1(ax)
+    return n
+
+
 def _fsdp_mean(grads, spec_tree):
     """FSDP leaves got SUM over data from the reduce-scatter; divide."""
     d = axis_size_or_1(AXES.data)
@@ -106,30 +115,14 @@ def opt_state_pspecs(opt_name: str, spec_tree):
 # ---------------------------------------------------------------------------
 
 
-def make_step_fns(cfg: ModelConfig, *, n_micro: int = 1,
-                  compress: str = "none", base_lr: float = 3e-4,
-                  warmup: int = 100, total_steps: int = 10_000):
-    """Returns (init_fn, train_fn) operating on SHARD-LOCAL values.
+def make_grad_fn(cfg: ModelConfig, *, n_micro: int = 1,
+                 compress: str = "none"):
+    """Returns grad_fn(params, batch) -> (loss, grads) on SHARD-LOCAL
+    values: this shard's mean loss, and the global mean gradient of every
+    leaf, synchronized across shards and sharded like the params."""
 
-    init_fn(key)                     -> (params, opt_state)
-    train_fn(params, opt, batch, i)  -> (params, opt, metrics)
-    """
-    opt_init, opt_update = get_optimizer(cfg.optimizer)
-
-    def spec_tree():
-        return lm.model_specs(cfg, axis_size_or_1(AXES.model))
-
-    def init_fn(key):
-        fold = 0
-        if has_axis(AXES.data):
-            fold = lax.axis_index(AXES.data) * axis_size_or_1(AXES.model)
-        if has_axis(AXES.model):
-            fold = fold + lax.axis_index(AXES.model)
-        params = init_tree(spec_tree(), key, fold=fold)
-        return params, opt_init(params)
-
-    def train_fn(params, opt_state, batch, step_idx):
-        specs = spec_tree()
+    def grad_fn(params, batch):
+        specs = lm.model_specs(cfg, axis_size_or_1(AXES.model))
 
         def loss_of(p, mb):
             return lm.loss_fn(p, cfg, mb)[0]
@@ -158,13 +151,42 @@ def make_step_fns(cfg: ModelConfig, *, n_micro: int = 1,
         with api.phase("bwd"):
             grads = _fsdp_mean(grads, specs)
             grads = finalize_grads(grads, specs, compress=compress)
+        return loss, grads
+
+    return grad_fn
+
+
+def make_step_fns(cfg: ModelConfig, *, n_micro: int = 1,
+                  compress: str = "none", base_lr: float = 3e-4,
+                  warmup: int = 100, total_steps: int = 10_000):
+    """Returns (init_fn, train_fn) operating on SHARD-LOCAL values.
+
+    init_fn(key)                     -> (params, opt_state)
+    train_fn(params, opt, batch, i)  -> (params, opt, metrics)
+    """
+    opt_init, opt_update = get_optimizer(cfg.optimizer)
+    grad_fn = make_grad_fn(cfg, n_micro=n_micro, compress=compress)
+
+    def spec_tree():
+        return lm.model_specs(cfg, axis_size_or_1(AXES.model))
+
+    def init_fn(key):
+        params = init_tree(spec_tree(), key)
+        return params, opt_init(params)
+
+    def train_fn(params, opt_state, batch, step_idx):
+        specs = spec_tree()
+        loss, grads = grad_fn(params, batch)
         lr = lr_schedule(step_idx, base_lr=base_lr, warmup=warmup,
                          total=total_steps)
         params, opt_state = opt_update(grads, opt_state, params, lr=lr)
 
-        # metrics: global mean loss + grad-norm (cheap diagnostics)
-        gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                  for g in jax.tree.leaves(grads))
+        # metrics: global mean loss + grad-norm (cheap diagnostics); each
+        # leaf's local sum is split over its replicas, so that the psums
+        # below count every global element once
+        gsq = sum(jax.tree.leaves(_map_with_specs(
+            lambda g, s: jnp.sum(jnp.square(g.astype(jnp.float32)))
+            / _replicas(s), grads, specs)))
         for ax in (AXES.data, AXES.model, AXES.pod):
             if has_axis(ax):
                 gsq = api.allreduce(gsq[None], ax)[0]
@@ -204,7 +226,7 @@ class Trainer:
                          force=self.force, record=self.record)
 
     def __post_init__(self):
-        from repro._compat import shard_map
+        from jax import shard_map
         self.tp = (self.mesh.shape.get("model", 1) if self.mesh else 1)
         self.specs = lm.model_specs(self.cfg, self.tp)
         self.pspecs = tree_pspecs(self.specs)

@@ -14,7 +14,6 @@ multi-device SPMD tests run in subprocesses (see tests/test_spmd_subprocess)
 which set their own ``--xla_force_host_platform_device_count``.
 """
 import os
-import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("PYTHONHASHSEED", "0")
@@ -23,14 +22,6 @@ import numpy as np
 import pytest
 
 SEED = 20170701
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_stub
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies
 
 
 def pytest_configure(config):

@@ -10,7 +10,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import repro._compat as compat
 from repro.core import api, costmodel as cm, measure, tuner
 from repro.core.cell import Geom, OpCell
 from repro.core.profiles import (Profile, ProfileStore, Range,
@@ -267,28 +266,6 @@ def test_tune_sweep_emits_geomless_profiles_for_fused_ops():
                      backend=tuner.CostModelBackend(cm.V5E_ICI))
     prof = rep.profiles.get("allgather_matmul", 8)
     assert prof is not None and prof.geom is None
-
-
-# ---------------------------------------------------------------------------
-# _compat self-disabling shims
-# ---------------------------------------------------------------------------
-
-
-def test_compat_shims_probe_native_api():
-    """Each shim self-disables when the native jax surface exists: the
-    LIVE_SHIMS registry must agree with what this jax actually provides."""
-    assert isinstance(compat.LIVE_SHIMS, list)
-    has_native_sm = hasattr(jax, "shard_map")
-    assert any("shard_map" in s for s in compat.LIVE_SHIMS) == \
-        (not has_native_sm)
-    has_fwp = hasattr(jax.tree, "flatten_with_path")
-    assert any("flatten_with_path" in s for s in compat.LIVE_SHIMS) == \
-        (not has_fwp)
-    # the wrappers keep working regardless of which branch is live
-    leaves, _ = compat.tree_flatten_with_path({"a": 1, "b": [2, 3]})
-    assert len(leaves) == 3
-    mesh = compat.mesh_with_axis_types(np.array(jax.devices()[:1]), ("x",))
-    assert mesh.shape["x"] == 1
 
 
 def test_tune_trace_geometryless_fused_cell_note_in_footer():

@@ -151,7 +151,7 @@ def test_allgather_matmul_grads(rng, impl):
         dx, dw = _grads(f, x, w)
     # reference: unfused composition with the same gather<->scatter pairing
     def ref(a, ww):
-        full = ops.tp_allgather(a, 0, "model")
+        full = ops.fsdp_gather(a, 0, "model")
         return jnp.matmul(full, ww)
 
     rx, rw = _grads(ref, x, w)
@@ -314,7 +314,6 @@ def test_lm_train_trace_contains_fused_ops_and_tuner_splits(rng):
     allgather_matmul (fused weight gather) and matmul_reducescatter (grad
     reduce-scatter) cells, and trace-replay tuning on the cost model picks
     fused_ring for at least one of them."""
-    from jax import lax
 
     from repro.configs import get_config
     from repro.models import lm
@@ -325,8 +324,7 @@ def test_lm_train_trace_contains_fused_ops_and_tuner_splits(rng):
     batch["labels"] = batch["tokens"]
 
     def init(key):
-        return init_tree(lm.model_specs(cfg, tp=1), key,
-                         fold=lax.axis_index("data"))
+        return init_tree(lm.model_specs(cfg, tp=1), key)
 
     def grad_fn(params):
         return jax.grad(lambda p: lm.loss_fn(p, cfg, batch)[0])(params)

@@ -71,7 +71,7 @@ def test_force_reaches_every_dist_op():
         y = ops.tp_allreduce(a, "model")
         y = ops.tp_copy(y, "model") * 0.5
         y = ops.ep_alltoall(y, "model")
-        y = ops.tp_allgather(ops.tp_reducescatter(y, 0, "model"), 0, "model")
+        y = ops.fsdp_gather(ops.tp_reducescatter(y, 0, "model"), 0, "model")
         return jnp.sum(y * a)
 
     with api.tuned(force=force) as ctx:
@@ -124,8 +124,7 @@ def test_lm_fwd_bwd_dispatches_both_directions(ag_impl):
     batch["labels"] = batch["tokens"]
 
     def init(key):
-        return init_tree(lm.model_specs(cfg, tp=1), key,
-                         fold=lax.axis_index("data"))
+        return init_tree(lm.model_specs(cfg, tp=1), key)
 
     def grad_fn(params):
         return jax.grad(lambda p: lm.loss_fn(p, cfg, batch)[0])(params)
@@ -157,8 +156,7 @@ def test_lm_swapped_force_changes_recorded_selection():
 
     def run(force):
         def init(key):
-            return init_tree(lm.model_specs(cfg, tp=1), key,
-                             fold=lax.axis_index("data"))
+            return init_tree(lm.model_specs(cfg, tp=1), key)
 
         def grad_fn(params):
             return jax.grad(lambda p: lm.loss_fn(p, cfg, batch)[0])(params)

@@ -60,6 +60,21 @@ ref_scatter.defvjp(
     lambda dim, axis, _, g: (_moved(lambda a: _lax_ag(a, axis), g, dim),))
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def ref_gather_split(dim, axis, x):
+    return _moved(lambda a: _lax_ag(a, axis), x, dim)
+
+
+def _own_block(dim, axis, g):
+    n = g.shape[dim] // lax.axis_size(axis)
+    return lax.dynamic_slice_in_dim(g, lax.axis_index(axis) * n, n, dim)
+
+
+ref_gather_split.defvjp(
+    lambda dim, axis, x: (ref_gather_split(dim, axis, x), None),
+    lambda dim, axis, _, g: (_own_block(dim, axis, g),))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def ref_allreduce(axis, x):
     return lax.psum(x, axis)
@@ -122,8 +137,11 @@ CASES = [
      lambda a: ref_gather(0, AXIS, a)),
     ("fsdp_gather_d1", lambda a: ops.fsdp_gather(a, 1, AXIS),
      lambda a: ref_gather(1, AXIS, a)),
-    ("tp_allgather_last", lambda a: ops.tp_allgather(a, a.ndim - 1, AXIS),
+    ("fsdp_gather_last", lambda a: ops.fsdp_gather(a, a.ndim - 1, AXIS),
      lambda a: ref_gather(1, AXIS, a)),
+    ("tp_allgather_split_last",
+     lambda a: ops.tp_allgather_split(a, a.ndim - 1, AXIS),
+     lambda a: ref_gather_split(1, AXIS, a)),
     ("tp_reducescatter", lambda a: ops.tp_reducescatter(a, 0, AXIS),
      lambda a: ref_scatter(0, AXIS, a)),
     ("tp_allreduce", lambda a: ops.tp_allreduce(a, AXIS),

@@ -139,7 +139,7 @@ import json
 import jax, jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from repro._compat import shard_map
+from jax import shard_map
 from repro.launch.mesh import make_host_mesh
 from repro.analysis.hlo import _loop_multipliers, collective_bytes, collective_sites
 
@@ -245,7 +245,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro._compat import shard_map
+from jax import shard_map
 from repro.launch.mesh import make_host_mesh
 from repro.core import api
 from repro.analysis.interpose import assert_bitexact, rewrite

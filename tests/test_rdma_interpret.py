@@ -157,11 +157,9 @@ def test_interpret_blocks_nontrivial_dtype(rng):
 
 
 def test_real_rdma_path_stays_tpu_gated():
-    """CPU CI imports this module now (interpret tier), but the dispatcher
-    fused_ring impl must still never take the RDMA path off-TPU."""
-    assert not cmm.on_tpu()
-    # the fused_ring impl on CPU routes to the ppermute reference; if it
-    # tried the RDMA kernel, make_async_remote_copy would fail to lower
+    """The dispatcher's fused_ring impl is the ppermute ring on every
+    backend: it never reaches the RDMA kernel, whose
+    make_async_remote_copy would fail to lower here."""
     from repro.core import collectives as C
     x = jnp.ones((4, 2, 3), jnp.float32)
     w = jnp.ones((3, 2), jnp.float32)

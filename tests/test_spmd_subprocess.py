@@ -21,7 +21,7 @@ EQUIV_SCRIPT = r"""
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
-from repro._compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.models import lm
@@ -64,8 +64,21 @@ dl = abs(float(m1["loss"]) - float(m8["loss"]))
 dp = max(float(np.max(np.abs(np.asarray(a, np.float32)
                              - np.asarray(b, np.float32))))
          for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p8)))
+dg = abs(float(m1["grad_norm"]) / float(m8["grad_norm"]) - 1.0)
+
+# a sharded init must give every replica of a leaf the same values
+pi, _ = jax.jit(shard_map(init_fn, mesh=mesh, in_specs=P(),
+                          out_specs=(pspecs, opt_ps), check_vma=False))(
+    jax.random.key(7))
+split = []
+for path, leaf in jax.tree.flatten_with_path(pi)[0]:
+    held = {}
+    for s in leaf.addressable_shards:
+        held.setdefault(str(s.index), []).append(np.asarray(s.data))
+    if any(not np.array_equal(v[0], a) for v in held.values() for a in v):
+        split.append(jax.tree_util.keystr(path))
 import json
-print(json.dumps({"dl": dl, "dp": dp}))
+print(json.dumps({"dl": dl, "dp": dp, "dg": dg, "split": split}))
 """
 
 
@@ -105,6 +118,9 @@ def test_spmd_equivalence(arch):
     # trips even with the looser loss tolerance.
     assert out["dl"] < (1e-1 if moe else 1e-2), out
     assert out["dp"] < 5e-2, out
+    # the grad-norm metric counts each global element once, replicas or not
+    assert out["dg"] < (1e-1 if moe else 1e-2), out
+    assert out["split"] == [], out
 
 
 FUSED_MM_SCRIPT = r"""
@@ -112,7 +128,7 @@ import os, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro._compat import shard_map
+from jax import shard_map
 from repro.core import api
 from repro.dist import ops
 
@@ -155,17 +171,20 @@ out["oracle_agmm"] = float(np.abs(
 # matmul_accumulate (contraction-dim ring) over a data axis: w K-sharded,
 # x shard-local; compare fused vs unfused values + weight grads, and the
 # REWIRED col_matmul(fsdp_dim=0) K-gather site vs the legacy composition
-# bit-for-bit under default dispatch (the acceptance criterion).
+# bit-for-bit under default dispatch (the acceptance criterion).  Integer-
+# valued operands and cotangent keep every reduction order exact, so the
+# comparison sees the composition and not the order XLA picks per dot.
 mesh_d = Mesh(np.array(jax.devices()), ("data",))
 kloc, T, M = 4, 6, 5
 K = p * kloc
-xs = jnp.asarray(rng.normal(size=(T, K)).astype(np.float32))
-wacc = jnp.asarray(rng.normal(size=(K, M)).astype(np.float32))
+xs = jnp.asarray(np.round(rng.normal(size=(T, K)) * 2).astype(np.float32))
+wacc = jnp.asarray(np.round(rng.normal(size=(K, M)) * 2).astype(np.float32))
+cot_i = lambda y: jnp.round(cot(y) * 4)
 
 def run_acc(f, force):
     def body(wb):
         val = f(wb)
-        g = jax.grad(lambda b: jnp.sum(f(b) * cot(f(b))))(wb)
+        g = jax.grad(lambda b: jnp.sum(f(b) * cot_i(f(b))))(wb)
         return val, g
     sm = shard_map(body, mesh=mesh_d, in_specs=P("data"),
                    out_specs=(P(), P("data")), check_vma=False)
@@ -201,7 +220,7 @@ import os, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro._compat import shard_map
+from jax import shard_map
 from repro.core import api
 from repro.dist import ops
 
@@ -276,6 +295,12 @@ t = Trace([TraceEntry.of("allreduce", 4, 1024, "decode", "default", 5),
                          mm_n=2 * 6, mm_role="2d", p2=2)])
 backend = tuner.MeasuredBackend(K=2, max_nrep=3)
 rep = tuner.tune_trace(t, backend=backend)
+# a backend on a 2-device sub-mesh measures the p=2 cell of a 2x2 step,
+# at the 2x2 step's own sub-axis size, and skips the p=4 cell
+t2 = Trace([TraceEntry.of("allreduce", 2, 4096, "bwd", "default", 3),
+            TraceEntry.of("allreduce", 4, 4096, "bwd", "default", 3)])
+sub2 = tuner.tune_trace(t2, backend=tuner.MeasuredBackend(
+    K=2, max_nrep=3, axis_size=2), min_win=0.0)
 print(json.dumps({
     "sup": backend.supported_axis_size,
     "n_meas": len(rep.measurements),
@@ -283,6 +308,8 @@ print(json.dumps({
                      if m.cell.op == "matmul_reducescatter_2d"),
     "skips": [n for n in rep.notes if "host axis size" in n],
     "est_default": rep.est_default_s.get("decode", 0.0),
+    "sub2_p": sorted({m.cell.p for m in sub2.measurements}),
+    "sub2_skips": [n for n in sub2.notes if "host axis size 2" in n],
 }))
 """
 
@@ -333,7 +360,8 @@ def test_row_matmul_2d_spmd_equivalence_4dev():
 def test_measured_backend_trace_replay_4dev():
     """ROADMAP item: replay a recorded trace's cells on real host devices —
     the p=4 cell AND the (2,2)-world 2-D cell are wall-clock measured
-    (both impls each), the p=8 cell skips with a note."""
+    (both impls each), the p=8 cell skips with a note; a backend on a
+    2-device sub-mesh measures p=2 cells and skips the p=4 one."""
     r = _run(MEASURED_REPLAY_SCRIPT)
     assert r.returncode == 0, r.stdout + r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
@@ -342,6 +370,8 @@ def test_measured_backend_trace_replay_4dev():
     assert out["n_meas_2d"] >= 2, out        # 2-D replay on the 2x2 mesh
     assert out["skips"], out                 # p=8 cell noted as skipped
     assert out["est_default"] > 0.0
+    assert out["sub2_p"] == [2], out
+    assert len(out["sub2_skips"]) == 1, out
 
 
 @pytest.mark.slow
@@ -355,3 +385,6 @@ def test_spmd_equivalence_pod_axis():
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["dl"] < 1e-2, out
     assert out["dp"] < 5e-2, out
+    assert out["dg"] < 1e-2, out
+    assert out["split"] == [], out
+

@@ -14,7 +14,6 @@ from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from repro.core import api, tuner
 from repro.core.cell import OpCell
@@ -202,6 +201,26 @@ def test_tune_trace_respects_min_win_and_default_inf():
     assert any("allgather" in n and "unmeasurable" in n for n in rep.notes)
 
 
+def test_tune_trace_one_range_per_size_across_dtypes():
+    """One size recorded in two dtypes is one profile range: the cells
+    decide together, by weighted latency, instead of each emitting a range
+    of its own (two impls at one size raised "overlapping ranges")."""
+    class ByDtype(_StubBackend):
+        def latency(self, cell, impl):
+            return {("float32", "allreduce_as_doubling"): 1.0,
+                    ("bfloat16", "allreduce_as_rsb_allgather"): 1.0,
+                    }.get((cell.dtype, impl), 10.0)
+
+    t = Trace([_mk(nbytes=4096, dtype="float32", count=1),
+               _mk(nbytes=4096, dtype="bfloat16", count=3)])
+    rep = tuner.tune_trace(t, backend=ByDtype({}), min_win=0.0)
+    prof = rep.phase_profiles["fwd"].get("allreduce", 8)
+    assert [(r.lo, r.hi, r.impl) for r in prof.ranges] == \
+        [(4096, 4096, "allreduce_as_rsb_allgather")]
+    assert rep.est_default_s["fwd"] == pytest.approx(40.0)
+    assert rep.est_tuned_s["fwd"] == pytest.approx(13.0)
+
+
 def test_tune_trace_save_roundtrips_through_load_stores(tmp_path):
     t = Trace([_mk(op="allreduce", p=8, nbytes=256, phase="decode"),
                _mk(op="reducescatter", p=8, nbytes=512, phase="bwd")])
@@ -261,8 +280,7 @@ def _lm_step_ctx(phase_profiles=None):
     batch["labels"] = batch["tokens"]
 
     def init(key):
-        return init_tree(lm.model_specs(cfg, tp=1), key,
-                         fold=lax.axis_index("data"))
+        return init_tree(lm.model_specs(cfg, tp=1), key)
 
     def grad_fn(params):
         return jax.grad(lambda p: lm.loss_fn(p, cfg, batch)[0])(params)
