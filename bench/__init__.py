@@ -1,0 +1,1 @@
+"""Chip benchmark: one command runs one cell of ``BENCHMARK.json`` once."""
