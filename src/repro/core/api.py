@@ -39,6 +39,8 @@ import dataclasses
 import os
 import threading
 
+import jax
+
 from repro.core import collectives as C
 from repro.core._axis import axis_size
 from repro.core.cell import OP_MM_ROLE, OpCell
@@ -569,7 +571,7 @@ def _dispatch_plan(op: str, payload, axis: str, ctx: TuneContext,
     # fallback — vmap-emulated axes must be told to pad proactively
     axes = [a for a in (axis, kw.get("rs_axis"))
             if isinstance(a, str) and axis_is_vmapped(a)]
-    with force_full_perm(axes):
+    with force_full_perm(axes), jax.named_scope(f"pgtune.{op}.{PLAN_IMPL}"):
         return lax.switch(idx, branches, 0)
 
 
@@ -586,7 +588,8 @@ def _dispatch(op: str, payload, axis: str, impl: str | None, /, **kw):
             if out is not _NO_PLAN:
                 return out
     name = _select(op, payload, axis, impl, kw)
-    return C.REGISTRY[op][name].fn(payload, axis, **kw)
+    with jax.named_scope(f"pgtune.{op}.{name}"):
+        return C.REGISTRY[op][name].fn(payload, axis, **kw)
 
 
 # -- public entry points -----------------------------------------------------

@@ -17,6 +17,17 @@ On a pod pass ``--mesh 16x16`` / ``--mesh 2x16x16``; ``--mesh DxT`` builds a
 (data, model) mesh over the first D*T local devices.  Without ``--mesh``
 the step is a plain ``jit`` and every ``dist.ops`` collective degrades to
 its local meaning, so the tuner sees no traffic.
+
+``--trace-dir DIR --trace-steps A:B`` writes a JAX profile of steps A to B
+under ``DIR`` (TensorBoard's profile plugin or ``jax.profiler.ProfileData``
+read it): the profiler starts before step A and stops once step B has
+finished.  Its device ops carry the program's scopes in their ``op_name``
+(``embed``, ``head``, one per block kind such as ``rwkv`` or ``mamba``,
+``wkv``, ``ssd``, ``grad_sync``, ``optimizer``, and ``pgtune.<op>.<impl>``
+at each dispatched collective); its host line holds the spans
+``train.step`` (with the step number), ``train.put_batch``, ``train.wait``
+and ``ckpt.save``.  A step that builds an executable after the first step
+prints a ``recompile:`` line.
 """
 from __future__ import annotations
 
@@ -65,7 +76,14 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-dir", default="results/train_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--trace-dir", default="",
+                    help="write a profiler trace of --trace-steps here")
+    ap.add_argument("--trace-steps", default="1:2",
+                    help="A:B, the first and last step of the trace")
     args = ap.parse_args(argv)
+    trace_a, trace_b = (int(x) for x in args.trace_steps.split(":"))
+    if args.trace_dir and not 0 <= trace_a <= trace_b:
+        ap.error(f"--trace-steps {args.trace_steps}: want 0 <= A <= B")
 
     import jax
 
@@ -122,7 +140,8 @@ def main(argv=None) -> int:
     def report(i, m):
         # a step's time runs from the previous step's result to its own, so
         # the host prepares step i + 1 while the device runs step i
-        jax.block_until_ready(m["loss"])
+        with jax.profiler.TraceAnnotation("train.wait"):
+            jax.block_until_ready(m["loss"])
         straggler = wd.end_step()
         wd.start_step()
         if i % args.log_every == 0 or straggler:
@@ -136,18 +155,32 @@ def main(argv=None) -> int:
     t0 = time.time()
     wd.start_step()
     pending = None
+    tracing = False
     for i in range(start, args.steps):
+        if args.trace_dir and i == trace_a:
+            jax.profiler.start_trace(args.trace_dir)
+            tracing = True
         batch = tr.put_batch(make_batch(cfg, args.global_batch, args.seq, i))
         params, opt, m = tr.step(params, opt, batch, i)
         if i == start:
             print(dispatch_summary(record), flush=True)
+        if tr.recompile_step == i:
+            print(f"recompile: step {i} built a program; {tr.recompiles} "
+                  f"executable(s) built after the first step", flush=True)
         if pending is not None:
             report(*pending)
         pending = (i, m)
         if (i + 1) % args.ckpt_every == 0:
-            acp.save(i + 1, {"params": params, "opt": opt})
+            with jax.profiler.TraceAnnotation("ckpt.save"):
+                acp.save(i + 1, {"params": params, "opt": opt})
+        if tracing and i == trace_b:
+            jax.block_until_ready(m)
+            jax.profiler.stop_trace()
+            tracing = False
     if pending is not None:
         report(*pending)
+    if tracing:
+        jax.profiler.stop_trace()
     acp.wait()
     ck.save(args.ckpt_dir, args.steps, {"params": params, "opt": opt})
     dt = time.time() - t0

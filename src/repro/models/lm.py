@@ -321,28 +321,30 @@ def _cross_kv(p, cfg: ModelConfig, enc_out):
 
 def _run_block(kind, p, cfg, x, *, pos, mode, cache, n_prefix, enc_out,
                use_rope, shared_p=None, resid0=None, seq_sharded=False):
-    if kind in ("attn", "attn_local"):
-        return _run_attn_block(p, cfg, x, kind=kind, pos=pos, mode=mode,
-                               cache=cache, n_prefix=n_prefix,
-                               enc_out=enc_out, use_rope=use_rope,
-                               seq_sharded=seq_sharded)
-    if kind == "shared_attn":
-        # zamba2: shared transformer block on concat(x, resid0), projected in
-        h = ops.matmul_accumulate(jnp.concatenate([x, resid0], axis=-1),
-                                  shared_p["proj_in"])
-        shared_cfg = dataclasses.replace(cfg, moe=None, mla=None)
-        y, c, aux = _run_attn_block(
-            shared_p, shared_cfg, h, kind="attn", pos=pos, mode=mode,
-            cache=cache, n_prefix=n_prefix, enc_out=None, use_rope=use_rope,
-            seq_sharded=seq_sharded)
-        return x + y, c, aux
-    if kind == "rwkv":
-        y, st = ssm_mod.rwkv_block(p, cfg, x, state=cache)
-        return y, st, jnp.float32(0.0)
-    if kind == "mamba":
-        y, st = ssm_mod.mamba_block(p, cfg, x, state=cache)
-        return y, st, jnp.float32(0.0)
-    raise ValueError(kind)
+    with jax.named_scope(kind):
+        if kind in ("attn", "attn_local"):
+            return _run_attn_block(p, cfg, x, kind=kind, pos=pos, mode=mode,
+                                   cache=cache, n_prefix=n_prefix,
+                                   enc_out=enc_out, use_rope=use_rope,
+                                   seq_sharded=seq_sharded)
+        if kind == "shared_attn":
+            # zamba2: shared transformer block on concat(x, resid0),
+            # projected in
+            h = ops.matmul_accumulate(jnp.concatenate([x, resid0], axis=-1),
+                                      shared_p["proj_in"])
+            shared_cfg = dataclasses.replace(cfg, moe=None, mla=None)
+            y, c, aux = _run_attn_block(
+                shared_p, shared_cfg, h, kind="attn", pos=pos, mode=mode,
+                cache=cache, n_prefix=n_prefix, enc_out=None,
+                use_rope=use_rope, seq_sharded=seq_sharded)
+            return x + y, c, aux
+        if kind == "rwkv":
+            y, st = ssm_mod.rwkv_block(p, cfg, x, state=cache)
+            return y, st, jnp.float32(0.0)
+        if kind == "mamba":
+            y, st = ssm_mod.mamba_block(p, cfg, x, state=cache)
+            return y, st, jnp.float32(0.0)
+        raise ValueError(kind)
 
 
 def _run_stack(params, cfg: ModelConfig, x, *, pos, mode, caches,
@@ -467,15 +469,17 @@ def forward(params, cfg: ModelConfig, batch, *, mode="train", caches=None,
     enc_out = None
     if cfg.encdec is not None and "frames" in batch:
         enc_out = _encode(params, cfg, batch["frames"])
-    x, pos, n_prefix = _embed_inputs(params, cfg, batch, pos0=pos0)
+    with jax.named_scope("embed"):
+        x, pos, n_prefix = _embed_inputs(params, cfg, batch, pos0=pos0)
     x, new_caches, aux = _run_stack(
         params, cfg, x, pos=pos, mode=mode, caches=caches,
         n_prefix=n_prefix, enc_out=enc_out, use_rope=use_rope,
         seq_sharded=seq_sharded)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(params["embed"], x,
-                       params.get("head") if not cfg.tie_embeddings else None,
-                       final_softcap=cfg.final_softcap)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = lm_logits(params["embed"], x,
+                           params.get("head") if not cfg.tie_embeddings
+                           else None, final_softcap=cfg.final_softcap)
     return logits, new_caches, aux
 
 
@@ -483,12 +487,13 @@ def loss_fn(params, cfg: ModelConfig, batch):
     """Next-token CE (text positions only for VLM).  Scalar local mean."""
     logits, _, aux = forward(params, cfg, batch, mode="train")
     labels = batch["labels"]
-    if cfg.vlm is not None:
-        n_img = cfg.vlm.n_patches
-        logits = logits[:, n_img:]
-    mask = batch.get("mask")
-    loss = sharded_xent(logits[:, :-1], labels[:, 1:],
-                        None if mask is None else mask[:, 1:])
+    with jax.named_scope("head"):
+        if cfg.vlm is not None:
+            n_img = cfg.vlm.n_patches
+            logits = logits[:, n_img:]
+        mask = batch.get("mask")
+        loss = sharded_xent(logits[:, :-1], labels[:, 1:],
+                            None if mask is None else mask[:, 1:])
     return loss + 0.01 * aux, {"nll": loss, "aux": aux}
 
 

@@ -140,7 +140,8 @@ def rwkv_block(p: dict, cfg: ModelConfig, x, *, state=None):
     u = p["u"].astype(f32).reshape(h_loc, hd)
     s0 = (state["s"].astype(f32) if state
           else jnp.zeros((b, h_loc, hd, hd), f32))
-    y, s_fin = _wkv_scan(rh, kh, vh, wh, u, s0)
+    with jax.named_scope("wkv"):
+        y, s_fin = _wkv_scan(rh, kh, vh, wh, u, s0)
     # per-head group norm (RWKV GroupNorm(n_heads)) — invariant under TP
     yh = y.astype(x.dtype)
     scale = p["ln_x"].reshape(h_loc, hd)
@@ -305,7 +306,8 @@ def mamba_block(p: dict, cfg: ModelConfig, x, *, state=None):
 
     s0 = (state["s"].astype(f32) if state
           else jnp.zeros((b, h_loc, n, c.head_dim), f32))
-    y, s_fin = _ssd_chunked(xh.astype(f32), dt, a, B, C, s0, c.chunk)
+    with jax.named_scope("ssd"):
+        y, s_fin = _ssd_chunked(xh.astype(f32), dt, a, B, C, s0, c.chunk)
     y = y + xh.astype(f32) * p["d_skip"].astype(f32)[None, None, :, None]
     yh = y.astype(x.dtype)                      # [b,s,h_loc,P]
     scale = p["gate_norm"].reshape(h_loc, c.head_dim)

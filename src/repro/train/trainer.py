@@ -29,6 +29,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import api
 from repro.dist.axes import AXES, axis_size_or_1, has_axis
 from repro.models import lm
@@ -148,7 +149,7 @@ def make_grad_fn(cfg: ModelConfig, *, n_micro: int = 1,
 
         # grad sync is backward-phase traffic: the trace-replay tuner may
         # give these allreduces a different profile than fwd collectives
-        with api.phase("bwd"):
+        with jax.named_scope("grad_sync"), api.phase("bwd"):
             grads = _fsdp_mean(grads, specs)
             grads = finalize_grads(grads, specs, compress=compress)
         return loss, grads
@@ -179,7 +180,8 @@ def make_step_fns(cfg: ModelConfig, *, n_micro: int = 1,
         loss, grads = grad_fn(params, batch)
         lr = lr_schedule(step_idx, base_lr=base_lr, warmup=warmup,
                          total=total_steps)
-        params, opt_state = opt_update(grads, opt_state, params, lr=lr)
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt_update(grads, opt_state, params, lr=lr)
 
         # metrics: global mean loss + grad-norm (cheap diagnostics); each
         # leaf's local sum is split over its replicas, so that the psums
@@ -219,6 +221,11 @@ class Trainer:
     base_lr: float = 3e-4
     warmup: int = 100
     record: list | None = None           # shared dispatch-record sink
+    # executables built by ``step`` calls after the first, and the step
+    # that built the last of them
+    recompiles: int = dataclasses.field(default=0, init=False)
+    recompile_step: int | None = dataclasses.field(default=None, init=False)
+    _stepped: bool = dataclasses.field(default=False, init=False)
 
     def _tuned(self):
         return api.tuned(profiles=self.profiles,
@@ -275,12 +282,21 @@ class Trainer:
             return self._init(jax.random.key(seed))
 
     def step(self, params, opt_state, batch, i):
-        with self._tuned():
-            return self._step(params, opt_state, batch,
-                              jnp.asarray(i, jnp.int32))
+        built, _ = obs.compiles()
+        with jax.profiler.TraceAnnotation("train.step", step=i), \
+                self._tuned():
+            out = self._step(params, opt_state, batch,
+                             jnp.asarray(i, jnp.int32))
+        built = obs.compiles()[0] - built
+        if built and self._stepped:
+            self.recompiles += built
+            self.recompile_step = i
+        self._stepped = True
+        return out
 
     def put_batch(self, batch):
-        if self.mesh is None:
-            return jax.tree.map(jnp.asarray, batch)
-        sp = NamedSharding(self.mesh, P(self._dp_axes()))
-        return jax.tree.map(lambda x: jax.device_put(x, sp), batch)
+        with jax.profiler.TraceAnnotation("train.put_batch"):
+            if self.mesh is None:
+                return jax.tree.map(jnp.asarray, batch)
+            sp = NamedSharding(self.mesh, P(self._dp_axes()))
+            return jax.tree.map(lambda x: jax.device_put(x, sp), batch)
