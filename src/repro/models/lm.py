@@ -405,8 +405,9 @@ def _run_stack(params, cfg: ModelConfig, x, *, pos, mode, caches,
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.nothing_saveable)
 
-        (x, aux_total), ncs = lax.scan(
-            body, (x, aux_total), gp if gc is None else (gp, gc))
+        with jax.named_scope("layers"):
+            (x, aux_total), ncs = lax.scan(
+                body, (x, aux_total), gp if gc is None else (gp, gc))
         new_caches["stack"][g.name] = ncs if gc is not None else None
 
     return x, (new_caches if caches is not None else None), aux_total

@@ -11,9 +11,11 @@ no marker.
 Reference semantics here are pure JAX:
 * mamba2 — chunked SSD (scalar per-head decay ⇒ the [L, L] pairwise decay
   matrix is stable and cheap);
-* rwkv6  — ``lax.scan`` over time (channel-wise decay cannot be factored
-  into one stable matmul; the chunked/blocked version is exactly what the
-  Pallas kernel implements in VMEM).
+* rwkv6  — chunked in XLA (``_wkv_scan``): channel-wise decay cannot be
+  factored into one stable matmul, so each chunk forms its pairwise decay
+  differences (every exponent ≤ 0), and a ``lax.scan`` carries one state
+  per chunk.  The Pallas kernel ``kernels/rwkv6_scan.py`` does the same
+  math in VMEM; it has no backward and is on no path.
 
 Decode carries O(1) state: (conv tail / last token, S).
 """
@@ -85,24 +87,72 @@ def _lerp(x, prev, mu):
     return x + (prev - x) * mu
 
 
-def _wkv_scan(r, k, v, w, u, s0):
-    """RWKV6 recurrence over time.
+# Tokens per chunk of the WKV recurrence (measured on the chip, PERF.md).
+# The pairwise decay tensor of a chunk grows with its square.
+WKV_CHUNK = 16
+# The chunk products run in full f32: at the default (one bf16 pass) the
+# bonus ``u``'s gradient strays several times further from a float32
+# reference than the per-token scan's did; their FLOPs are small.
+_EXACT = lax.Precision.HIGHEST
 
-    r,k,v: [B,S,H,hd]; w: [B,S,H,hd] decay in (0,1); u: [H,hd] bonus.
+
+def _wkv_scan(r, k, v, logw, u, s0):
+    """RWKV6 recurrence, chunked.  Per token:
+      y_t = r_t·S_{t-1} + (r_t·(u⊙k_t)) v_t
+      S_t = e^{logw_t} ⊙ S_{t-1} + k_tᵀv_t
+
+    r,k,v: [B,S,H,hd]; logw: [B,S,H,hd] log-decay (≤ 0); u: [H,hd] bonus;
     s0: [B,H,hd,hd].  Returns y [B,S,H,hd], s_final.
-    """
-    def step(s, rkvw):
-        rt, kt, vt, wt = rkvw                      # [B,H,hd]
-        kv = jnp.einsum("bhk,bhv->bhkv", kt, vt)
-        y = (jnp.einsum("bhk,bhkv->bhv", rt, s)
-             + jnp.einsum("bhk,bhkv->bhv", rt * u[None], kv))
-        s = wt[..., None] * s + kv
-        return s, y
 
-    xs = (r.transpose(1, 0, 2, 3), k.transpose(1, 0, 2, 3),
-          v.transpose(1, 0, 2, 3), w.transpose(1, 0, 2, 3))
-    s_fin, ys = lax.scan(step, s0, xs)
-    return ys.transpose(1, 0, 2, 3), s_fin
+    The row is cut into chunks of L = min(WKV_CHUNK, S) tokens, the tail
+    padded with k = v = r = 0 and log-decay 0, which leave y and the state
+    as they are.  Inside a chunk, with cum the log-decay summed from the
+    chunk's start and cum_prev = cum - logw:
+      intra:  y_t += Σ_{s<t} [Σ_c r_tc k_sc e^{cum_prev_tc - cum_sc}] v_s
+              + bonus diagonal
+      state:  y_t += (r_t ⊙ e^{cum_prev_t}) S_in, S_in the state the
+              chunk starts from, carried by a ``lax.scan`` over chunks:
+              S ← e^{cum_L} ⊙ S + (k ⊙ e^{cum_L - cum})ᵀ v
+    Every exponent is ≤ 0: the pairwise one is clamped before ``exp`` and
+    masked after it, so no gradient meets an overflow.
+    """
+    b, S, H, hd = r.shape
+    L = min(WKV_CHUNK, S)
+    nc = -(-S // L)
+
+    def chunked(x):                                # -> [nc,B,H,L,hd]
+        x = jnp.pad(x, ((0, 0), (0, nc * L - S), (0, 0), (0, 0)))
+        return x.reshape(b, nc, L, H, hd).transpose(1, 0, 3, 2, 4)
+
+    r, k, v, logw = map(chunked, (r, k, v, logw))
+    cum = jnp.cumsum(logw, axis=3)                 # ≤ 0, falls along L
+    cum_prev = cum - logw
+
+    with jax.named_scope("intra"):
+        diff = cum_prev[..., :, None, :] - cum[..., None, :, :]  # [..,t,s,c]
+        past = jnp.tril(jnp.ones((L, L), bool), -1)[..., None]  # s < t
+        dec = jnp.where(past, jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
+        a = jnp.sum(r[..., :, None, :] * k[..., None, :, :] * dec, axis=-1)
+        y = jnp.einsum("...ts,...sv->...tv", a, v, precision=_EXACT)
+        y = y + jnp.sum(r * u[:, None] * k, -1, keepdims=True) * v
+
+    with jax.named_scope("state"):
+        cum_l = cum[..., -1:, :]                   # [nc,B,H,1,hd]
+        kv = jnp.einsum("...tk,...tv->...kv", k * jnp.exp(cum_l - cum), v,
+                        precision=_EXACT)
+
+        def step(s, x):
+            d, kv_c, rq = x
+            return (d[..., None] * s + kv_c,
+                    jnp.einsum("bhtk,bhkv->bhtv", rq, s, precision=_EXACT))
+
+        s_fin, y_in = lax.scan(step, s0, (
+            jnp.exp(cum_l[..., 0, :]), kv, r * jnp.exp(cum_prev)),
+            unroll=8)              # 8 chunks an iteration: faster on the chip
+        y = y + y_in
+
+    y = y.transpose(1, 0, 3, 2, 4).reshape(b, nc * L, H, hd)[:, :S]
+    return y, s_fin
 
 
 def rwkv_block(p: dict, cfg: ModelConfig, x, *, state=None):
@@ -131,17 +181,17 @@ def rwkv_block(p: dict, cfg: ModelConfig, x, *, state=None):
     low = jnp.tanh(ops.matmul_accumulate(xw, p["wA"]))
     dec_raw = p["w0"].astype(f32) + ops.col_matmul(
         low, p["wB"]).astype(f32)
-    w = jnp.exp(-jnp.exp(dec_raw))                   # (0,1), per channel
+    logw = -jnp.exp(dec_raw)        # log of the decay w in (0,1), per channel
 
     rh = r.reshape(b, s, h_loc, hd).astype(f32)
     kh = k.reshape(b, s, h_loc, hd).astype(f32)
     vh = v.reshape(b, s, h_loc, hd).astype(f32)
-    wh = w.reshape(b, s, h_loc, hd)
+    logwh = logw.reshape(b, s, h_loc, hd)
     u = p["u"].astype(f32).reshape(h_loc, hd)
     s0 = (state["s"].astype(f32) if state
           else jnp.zeros((b, h_loc, hd, hd), f32))
     with jax.named_scope("wkv"):
-        y, s_fin = _wkv_scan(rh, kh, vh, wh, u, s0)
+        y, s_fin = _wkv_scan(rh, kh, vh, logwh, u, s0)
     # per-head group norm (RWKV GroupNorm(n_heads)) — invariant under TP
     yh = y.astype(x.dtype)
     scale = p["ln_x"].reshape(h_loc, hd)

@@ -7,10 +7,14 @@ no exporter of its own.
 Device scopes (``jax.named_scope``) name the ``op_name`` metadata of every
 instruction compiled inside them, so they cost nothing at run time:
 
-* ``embed``, ``head`` (final norm, logits, loss) and one scope per block
-  kind (``rwkv``, ``mamba``, ``attn``, ``attn_local``, ``shared_attn``) in
-  ``models/lm.py``; ``wkv`` (the RWKV6 recurrence) and ``ssd`` (Mamba2's
-  chunked scan) in ``models/ssm.py``;
+* ``embed``, ``head`` (final norm, logits, loss), ``layers`` (a scanned
+  layer group: the scan's own slicing and stacking of each layer's
+  parameters, residuals and gradients) and one scope per block kind
+  (``rwkv``, ``mamba``, ``attn``, ``attn_local``, ``shared_attn``) in
+  ``models/lm.py``; ``wkv`` (the RWKV6 recurrence) with ``wkv/intra``
+  (its intra-chunk part) and ``wkv/state`` (the chunk states, their scan
+  and the inter-chunk output) nested inside, and ``ssd`` (Mamba2's
+  chunked scan), in ``models/ssm.py``;
 * ``grad_sync`` and ``optimizer`` in ``train/trainer.py``;
 * ``pgtune.<op>.<impl>`` at each collective the dispatcher emits
   (``pgtune.<op>.plan`` for a runtime plan's switch), ``core/api.py``.

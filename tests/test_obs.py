@@ -28,8 +28,9 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 # arch -> (scopes the step must hold, scopes it must hold forward and
 # backward)
 SCOPES = {
-    "rwkv6-3b": ({"embed", "head", "optimizer", "grad_sync", "rwkv", "wkv"},
-                 {"wkv", "head", "embed"}),
+    "rwkv6-3b": ({"embed", "head", "optimizer", "grad_sync", "layers",
+                  "rwkv", "wkv", "intra", "state"},
+                 {"wkv", "intra", "state", "head", "embed"}),
     "zamba2-1.2b": ({"mamba", "ssd", "shared_attn", "head", "optimizer"},
                     {"ssd", "shared_attn"}),
     "llama3.2-3b": ({"attn", "head", "optimizer"}, {"attn"}),
