@@ -12,6 +12,7 @@ derive:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -27,7 +28,7 @@ Tree = dict[str, Any]   # nested dict of ParamSpec
 class ParamSpec:
     shape: tuple[int, ...]
     dims: tuple[str | None, ...]
-    init: str = "normal"      # normal | zeros | ones | scaled(fan-in)
+    init: str = "normal"      # normal | zeros | ones | a_log | dt_bias
     scale: float | None = None
     dtype: str = "bfloat16"
 
@@ -87,6 +88,15 @@ def _init_leaf(spec: ParamSpec, key, sizes: dict[str, int]):
         return jnp.zeros(shape, dt)
     if spec.init == "ones":
         return jnp.ones(shape, dt)
+    # Mamba2's published ranges: decay rates exp(a_log) ~ U[1, 16], steps
+    # softplus(dt_bias) log-uniform in [1e-3, 1e-1]
+    if spec.init == "a_log":
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32,
+                                          1.0, 16.0)).astype(dt)
+    if spec.init == "dt_bias":
+        step = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                          math.log(1e-3), math.log(1e-1)))
+        return jnp.log(jnp.expm1(step)).astype(dt)       # softplus⁻¹
     fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
     std = spec.scale if spec.scale is not None else fan_in ** -0.5
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dt)

@@ -9,8 +9,9 @@ whose backward already sums the input grad over the shards, so it needs
 no marker.
 
 Reference semantics here are pure JAX:
-* mamba2 — chunked SSD (scalar per-head decay ⇒ the [L, L] pairwise decay
-  matrix is stable and cheap);
+* mamba2 — chunked SSD (``_ssd_chunked``): a scalar decay per head, so a
+  chunk's [L, L] pairwise decay matrix, clamped before ``exp``, is stable
+  and cheap;
 * rwkv6  — chunked in XLA (``_wkv_scan``): channel-wise decay cannot be
   factored into one stable matmul, so each chunk forms its pairwise decay
   differences (every exponent ≤ 0), and a ``lax.scan`` carries one state
@@ -90,9 +91,11 @@ def _lerp(x, prev, mu):
 # Tokens per chunk of the WKV recurrence (measured on the chip, PERF.md).
 # The pairwise decay tensor of a chunk grows with its square.
 WKV_CHUNK = 16
-# The chunk products run in full f32: at the default (one bf16 pass) the
-# bonus ``u``'s gradient strays several times further from a float32
-# reference than the per-token scan's did; their FLOPs are small.
+# Products run in full f32: the WKV scan's three chunk products (at the
+# default, one bf16 pass, the bonus ``u``'s gradient strays several times
+# further from a float32 reference than the per-token scan's did) and the
+# SSD's intra-chunk pair; the SSD's chunk-state products run at the
+# default (PERF.md has the readings of each form).
 _EXACT = lax.Precision.HIGHEST
 
 
@@ -244,9 +247,9 @@ def mamba_specs(cfg: ModelConfig, tp: int) -> dict:
         "w_in_x": ParamSpec((d, di), ("data", "model"), dtype=dt),
         "w_bc": ParamSpec((d, 2 * n), ("data", None), dtype=dt),
         "w_dt": ParamSpec((d, nh), ("data", "model"), dtype=dt),
-        "dt_bias": ParamSpec((nh,), ("model",), init="zeros",
+        "dt_bias": ParamSpec((nh,), ("model",), init="dt_bias",
                              dtype="float32"),
-        "a_log": ParamSpec((nh,), ("model",), init="zeros", dtype="float32"),
+        "a_log": ParamSpec((nh,), ("model",), init="a_log", dtype="float32"),
         "d_skip": ParamSpec((nh,), ("model",), init="ones", dtype="float32"),
         "conv_x": ParamSpec((c.conv_kernel, di), (None, "model"),
                             scale=0.5, dtype=dt),
@@ -273,7 +276,18 @@ def _causal_conv(x, w, tail=None):
 
 def _ssd_chunked(xh, dt, a, B, C, s0, chunk: int):
     """Chunked SSD.  xh: [b,S,H,P]; dt: [b,S,H] (softplus'ed); a: [H] (>0);
-    B, C: [b,S,N]; s0: [b,H,N,P].  Returns y [b,S,H,P], s_fin."""
+    B, C: [b,S,N]; s0: [b,H,N,P].  Returns y [b,S,H,P], s_fin.
+
+    Per token and head: h_t = e^{-dt_t a} h_{t-1} + dt_t B_tᵀ x_t,
+    y_t = C_t h_t.  Inside a chunk of L tokens, with cum the log-decay
+    summed from the chunk's start:
+      intra:  y_t += Σ_{s≤t} (C_t·B_s) e^{cum_t - cum_s} dt_s x_s
+      state:  y_t += C_t (e^{cum_t} ⊙ S_in), S_in the state the chunk
+              starts from, carried by a ``lax.scan`` over chunks.
+    Every exponent is ≤ 0: the pairwise one is clamped before ``exp`` and
+    masked after it, so no gradient meets an overflow (over s > t it
+    would reach e^{L·dt·a}, inf at Mamba2's published decay rates).
+    """
     b, S, H, P = xh.shape
     N = B.shape[-1]
     L = min(chunk, S)
@@ -286,40 +300,44 @@ def _ssd_chunked(xh, dt, a, B, C, s0, chunk: int):
     xbar = xh * dt[..., None]                            # dt-scaled input
 
     lac = la_step.reshape(b, nc, L, H)
-    cum = jnp.cumsum(lac, axis=2)                        # within-chunk
+    cum = jnp.cumsum(lac, axis=2)                        # ≤ 0, falls along L
     Bc = B.reshape(b, nc, L, N)
     Cc = C.reshape(b, nc, L, N)
     Xc = xbar.reshape(b, nc, L, H, P)
 
-    # intra-chunk: M[t,s] = (C_t.B_s)·exp(cum_t - cum_s), s<=t
-    diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # [b,nc,L,L,H]
-    tri = jnp.tril(jnp.ones((L, L), bool))
-    dmat = jnp.where(tri[None, None, :, :, None], jnp.exp(diff), 0.0)
-    cb = jnp.einsum("bctn,bcsn->bcts", Cc.astype(f32), Bc.astype(f32))
-    m = cb[..., None] * dmat                              # [b,nc,L,L,H]
-    y_intra = jnp.einsum("bctsh,bcshp->bcthp", m, Xc.astype(f32))
+    with jax.named_scope("intra"):
+        # M[t,s] = (C_t·B_s) e^{cum_t - cum_s}, s ≤ t
+        diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # [b,nc,L,L,H]
+        tri = jnp.tril(jnp.ones((L, L), bool))[None, None, :, :, None]
+        dmat = jnp.where(tri, jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
+        cb = jnp.einsum("bctn,bcsn->bcts", Cc.astype(f32), Bc.astype(f32),
+                        precision=_EXACT)
+        m = cb[..., None] * dmat                          # [b,nc,L,L,H]
+        y_intra = jnp.einsum("bctsh,bcshp->bcthp", m, Xc.astype(f32),
+                             precision=_EXACT)
 
-    # per-chunk aggregates for the inter-chunk scan
-    # state in := sum_s exp(cum_L - cum_s) B_s xbar_s^T ; decay = exp(cum_L)
-    wlast = cum[:, :, -1:, :]                             # [b,nc,1,H]
-    kdec = jnp.exp(wlast - cum)                           # [b,nc,L,H]
-    s_in = jnp.einsum("bcln,bclh,bclhp->bchnp",
-                      Bc.astype(f32), kdec, Xc.astype(f32))
-    chunk_decay = jnp.exp(wlast[:, :, 0, :])              # [b,nc,H]
+    with jax.named_scope("state"):
+        # S_in of the next chunk += Σ_s e^{cum_L - cum_s} B_sᵀ xbar_s;
+        # the carried state decays by e^{cum_L} a chunk
+        wlast = cum[:, :, -1:, :]                         # [b,nc,1,H]
+        kdec = jnp.exp(wlast - cum)                       # [b,nc,L,H]
+        s_in = jnp.einsum("bcln,bclh,bclhp->bchnp",
+                          Bc.astype(f32), kdec, Xc.astype(f32))
+        chunk_decay = jnp.exp(wlast[:, :, 0, :])          # [b,nc,H]
 
-    def step(s, inp):
-        dec, sin, cdec, cq = inp
-        # y_inter[t] = C_t · (exp(cum_t) ⊙ s)   (decay applied to carry)
-        y = jnp.einsum("bln,blh,bhnp->blhp", cq, dec, s)
-        s = cdec[..., None, None] * s + sin
-        return s, y
+        def step(s, inp):
+            dec, sin, cdec, cq = inp
+            # y_inter[t] = C_t · (e^{cum_t} ⊙ s)
+            y = jnp.einsum("bln,blh,bhnp->blhp", cq, dec, s)
+            s = cdec[..., None, None] * s + sin
+            return s, y
 
-    xs = (jnp.exp(cum).transpose(1, 0, 2, 3),             # [nc,b,L,H]
-          s_in.transpose(1, 0, 2, 3, 4),                  # [nc,b,H,N,P]
-          chunk_decay.transpose(1, 0, 2),                 # [nc,b,H]
-          Cc.astype(f32).transpose(1, 0, 2, 3))           # [nc,b,L,N]
-    s_fin, y_inter = lax.scan(step, s0.astype(f32), xs)
-    y_inter = y_inter.transpose(1, 0, 2, 3, 4).reshape(b, S, H, P)
+        xs = (jnp.exp(cum).transpose(1, 0, 2, 3),         # [nc,b,L,H]
+              s_in.transpose(1, 0, 2, 3, 4),              # [nc,b,H,N,P]
+              chunk_decay.transpose(1, 0, 2),             # [nc,b,H]
+              Cc.astype(f32).transpose(1, 0, 2, 3))       # [nc,b,L,N]
+        s_fin, y_inter = lax.scan(step, s0.astype(f32), xs)
+        y_inter = y_inter.transpose(1, 0, 2, 3, 4).reshape(b, S, H, P)
     y = y_intra.reshape(b, S, H, P) + y_inter
     return y, s_fin
 

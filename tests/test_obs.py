@@ -31,8 +31,9 @@ SCOPES = {
     "rwkv6-3b": ({"embed", "head", "optimizer", "grad_sync", "layers",
                   "rwkv", "wkv", "intra", "state"},
                  {"wkv", "intra", "state", "head", "embed"}),
-    "zamba2-1.2b": ({"mamba", "ssd", "shared_attn", "head", "optimizer"},
-                    {"ssd", "shared_attn"}),
+    "zamba2-1.2b": ({"mamba", "ssd", "intra", "state", "shared_attn", "head",
+                     "optimizer"},
+                    {"ssd", "intra", "state", "shared_attn"}),
     "llama3.2-3b": ({"attn", "head", "optimizer"}, {"attn"}),
 }
 
@@ -63,6 +64,11 @@ def test_scopes_reach_the_compiled_step(arch):
         assert any("rwkv" in scopes.scopes_of(s)
                    and "wkv" not in scopes.scopes_of(s)
                    for s in names.values())
+    # the SSD scan's parts sit inside its scope: ssd/intra, ssd/state
+    if arch == "zamba2-1.2b":
+        for part in ("intra", "state"):
+            assert any({"ssd", part} <= scopes.scopes_of(s)
+                       for s in names.values()), part
     # each dispatch site names its op and the impl chosen
     assert any(p.startswith("pgtune.") and p.endswith(".default")
                for s in names.values() for p in scopes.scopes_of(s))
