@@ -35,6 +35,13 @@ from repro.models.layers import (embed_lookup, embed_specs, head_specs,
 from repro.models.moe import moe_block, moe_specs
 from repro.models.params import ParamSpec, stacked, tree_map_specs
 
+#: What a rematerialised layer scan keeps for its backward: the products
+#: with no batch dimension, which in these blocks are the weight
+#: projections (``dist/ops.py``'s matmuls, the LoRAs).  The batched
+#: products (WKV and SSD chunks, attention scores), norms and elementwise
+#: work are recomputed.
+REMAT_POLICY = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+
 
 # ---------------------------------------------------------------------------
 # stack plan: group the layer pattern into scannable units
@@ -402,8 +409,7 @@ def _run_stack(params, cfg: ModelConfig, x, *, pos, mode, caches,
                 return (xc, auxc), ncs
 
         if cfg.remat and mode == "train":
-            body = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.nothing_saveable)
+            body = jax.checkpoint(body, policy=REMAT_POLICY)
 
         with jax.named_scope("layers"):
             (x, aux_total), ncs = lax.scan(
@@ -452,8 +458,7 @@ def _encode(params, cfg: ModelConfig, frames):
         return xc, None
 
     if cfg.remat:
-        body = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.nothing_saveable)
+        body = jax.checkpoint(body, policy=REMAT_POLICY)
     x, _ = lax.scan(body, x, params["encoder"])
     return rms_norm(x, params["enc_final_norm"], cfg.norm_eps)
 
