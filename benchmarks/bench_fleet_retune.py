@@ -13,26 +13,25 @@ The continuous-retuning loop at serving scale, simulated on one host:
    Gate: on the union workload, the merged-trace profile's modeled cost
    is <= every single-shard profile's (a shard only sees its own slice,
    so its profile leaves the other servers' cells untuned).
-3. HOT SWAP — a serve loop built ONCE with ``api.tuned(store_ref=...,
-   plan=...)`` keeps stepping while the tuned generation is published to
-   ``$PGTUNE_PROFILE_DIR`` (profiles first, ``MANIFEST.json`` last).
-   ``StoreRef.poll`` adopts the new epoch, ``Plan.vector(ref)`` re-derives
-   the runtime dispatch vector, and the next steps serve the tuned impls.
-   Gate: ZERO new jit compilations across the swap (``_cache_size()``
-   instrumented) while the plan vector provably changed.
+3. HOT SWAP — a server serves under ``api.tuned(store_ref=...)`` while
+   the tuned generation is published to ``$PGTUNE_PROFILE_DIR`` (profiles
+   first, ``MANIFEST.json`` last).  ``StoreRef.poll`` adopts the new
+   epoch and the server rebuilds its steps: dispatch reads the ref at
+   trace time, so the new epoch reaches the next trace.  Gate: the
+   rebuilt steps' recorded dispatches are the new epoch's selection (at
+   least one tuned impl), and the generated tokens are unchanged.
 4. STALENESS — a delayed writer regressing the manifest to an older epoch
    is refused (warning, live generation keeps serving).
-5. EXPLORATION — an epsilon slice of steps runs ``Plan.explore`` vectors
-   (runner-up impls), latencies are fed back via
-   ``ShardRecorder.observe`` → ``#@lat`` shard lines →
+5. FEEDBACK — latencies of the impls the epoch-1 pass served are fed back
+   via ``ShardRecorder.observe`` → ``#@lat`` shard lines →
    ``tuner.FeedbackBackend``, and the next epoch is tuned from the
-   fleet's own measurements and hot-swapped in the same way.
+   fleet's own measurements and adopted in the same way.
 
 Wall-clock on this CPU container measures emulation overhead; decision
 quality is the cost-model latency (same convention as the other
-benchmarks).  Exploration "measurements" are therefore cost-model samples
-with noise — the plumbing (shards, reservoirs, feedback override) is what
-this benchmark exercises end to end.
+benchmarks).  The fed-back "measurements" are therefore cost-model
+samples with noise — the plumbing (shards, reservoirs, feedback override)
+is what this benchmark exercises end to end.
 
   PYTHONPATH=src python benchmarks/bench_fleet_retune.py --smoke
 """
@@ -75,51 +74,64 @@ def make_params(cfg, tp):
 
 
 def make_steps(cfg, tp, s_max, batch):
-    """Prefill/decode jits with a TRAILING replicated plan-vector arg —
-    the vector must be an argument (not a closure) so new epochs are new
-    VALUES to an already-compiled step, never new constants."""
+    """Cache-init, prefill and decode jits over the emulated model axis.
+    Each call builds fresh jits, so their first call traces under the
+    ambient ``api.tuned`` context."""
 
     def init_c(_):
         return lm.init_caches(cfg, batch, s_max)
 
-    def pf(p, c, prompts, vec):
-        with api.plan_input(vec):
-            return lm.prefill(p, cfg, {"tokens": prompts}, c)
+    def pf(p, c, prompts):
+        return lm.prefill(p, cfg, {"tokens": prompts}, c)
 
-    def dc(p, t, c, i, vec):
-        with api.plan_input(vec):
-            return lm.decode_step(p, cfg, t, c, i)
+    def dc(p, t, c, i):
+        return lm.decode_step(p, cfg, t, c, i)
 
     j_init = jax.jit(jax.vmap(init_c, axis_name="model", axis_size=tp,
                               in_axes=None, out_axes=0))
-    j_pf = jax.jit(jax.vmap(pf, axis_name="model",
-                            in_axes=(0, 0, None, None)))
+    j_pf = jax.jit(jax.vmap(pf, axis_name="model", in_axes=(0, 0, None)))
     j_dc = jax.jit(jax.vmap(dc, axis_name="model",
-                            in_axes=(0, None, 0, None, None)))
+                            in_axes=(0, None, 0, None)))
     return j_init, j_pf, j_dc
 
 
-def serve_pass(cfg, steps, params, prompts, n_tokens, vec):
+def serve_pass(cfg, steps, params, prompts, n_tokens):
     """One prefill + greedy decode pass, phase-tagged like launch/serve."""
     j_init, j_pf, j_dc = steps
     caches = j_init(0)
     with api.phase("prefill"):
-        logits, caches = j_pf(params, caches, prompts, vec)
+        logits, caches = j_pf(params, caches, prompts)
     tok = (jnp.argmax(logits[0][:, -1], axis=-1).astype(jnp.int32)[:, None]
            % cfg.vocab_size)
     out = [tok]
     with api.phase("decode"):
         for step in range(n_tokens - 1):
             lg, caches = j_dc(params, tok, caches,
-                              jnp.int32(prompts.shape[1] + step), vec)
+                              jnp.int32(prompts.shape[1] + step))
             tok = (jnp.argmax(lg[0][:, -1], axis=-1).astype(jnp.int32)
                    [:, None] % cfg.vocab_size)
             out.append(tok)
     return jnp.concatenate(out, axis=1)
 
 
-def cache_sizes(steps):
-    return tuple(s._cache_size() for s in steps)
+def live_pass(cfg, tp, s_max, params, prompts, n_tokens, ref):
+    """One pass on freshly built steps under the live ``ref`` — how a
+    server adopts an epoch, since dispatch reads the ref at trace time.
+    Returns the generated tokens and the dispatches the trace recorded."""
+    record: list = []
+    steps = make_steps(cfg, tp, s_max, prompts.shape[0])
+    with api.tuned(store_ref=ref, record=record):
+        gen = serve_pass(cfg, steps, params, prompts, n_tokens)
+        gen.block_until_ready()
+    return gen, record
+
+
+def _served_mismatch(record, ref):
+    """The recorded dispatches whose impl is not what the live stores
+    select (``_selection``) — empty when the epoch reached dispatch."""
+    return sorted({f"{r.op} {r.nbytes}B {r.phase}: {r.impl}"
+                   for r in record
+                   if r.impl != _selection(r.cell, r.phase, ref)})
 
 
 def main(argv=None) -> int:
@@ -130,8 +142,6 @@ def main(argv=None) -> int:
     ap.add_argument("--tokens", type=int, default=6)
     ap.add_argument("--topo", default="bgq-like", choices=sorted(cm.PRESETS))
     ap.add_argument("--min-win", type=float, default=0.10)
-    ap.add_argument("--eps", type=float, default=0.5,
-                    help="exploration budget (fraction of plan sites)")
     ap.add_argument("--out", default="results/fleet_retune")
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run (tiny fleet / token budget)")
@@ -146,9 +156,7 @@ def main(argv=None) -> int:
         return chaos_main(args)
 
     if args.smoke:
-        # eps=1 flips every multi-impl site — the exploration gate must be
-        # deterministic in CI, not a coin flip over a handful of sites
-        args.tokens, args.eps = 4, 1.0
+        args.tokens = 4
 
     topo = cm.PRESETS[args.topo]
     cfg = get_config(args.arch).smoke()
@@ -177,8 +185,7 @@ def main(argv=None) -> int:
         rec = ShardRecorder(f"srv{i}", seed=i)
         steps = make_steps(cfg, args.tp, s_max, batch)
         with api.tuned(record=rec):
-            serve_pass(cfg, steps, params, prompts, args.tokens,
-                       jnp.zeros(1, jnp.int32))
+            serve_pass(cfg, steps, params, prompts, args.tokens)
         path = rec.flush(shard_dir, epoch=1)
         emit(f"fleet_retune/shard{i}/dispatches",
              float(Trace.load(path).total()), path.name)
@@ -210,104 +217,84 @@ def main(argv=None) -> int:
                 f"merged profile costs {cost_merged:.3e}s on the union "
                 f"workload, worse than shard {i}'s profile ({cost_i:.3e}s)")
 
-    # -- 3. live serve + hot swap (zero re-jits) -----------------------------
+    # -- 3. live serve + hot swap (rebuild the steps on adoption) ----------
     os.environ[PROFILE_DIR_ENV] = str(live_dir)
     ref = resolve_stores(watch=True)
     if ref.epoch >= 0:
         failures.append(f"empty live dir resolved to epoch {ref.epoch}")
-    plan = api.Plan(capacity=64)
     batch, plen = fleet[1]
     params = make_params(cfg, args.tp)
     prompts = jnp.asarray(
         rng.integers(0, cfg.vocab_size, (batch, plen)), jnp.int32)
-    steps = make_steps(cfg, args.tp, s_max, batch)
+
+    def live():
+        return live_pass(cfg, args.tp, s_max, params, prompts, args.tokens,
+                         ref)
+
+    gen0, _ = live()
+
+    # publish epoch 1 (profiles first, MANIFEST last), then poll
+    rep.save(live_dir, epoch=1, source_digest=shard_digest(shard_dir))
+    swapped = ref.poll()
+    if not swapped or ref.epoch != 1:
+        failures.append(f"poll did not adopt epoch 1 "
+                        f"(swapped={swapped}, epoch={ref.epoch})")
+    gen1, rec_e1 = live()
+    tuned_sites = sum(r.impl != "default" for r in rec_e1)
+    emit("fleet_retune/epoch1_tuned_dispatches", float(tuned_sites),
+         f"of {len(rec_e1)} recorded")
+    if not tuned_sites:
+        failures.append("no tuned selection of epoch 1 reached dispatch")
+    bad = _served_mismatch(rec_e1, ref)
+    if bad:
+        failures.append(f"epoch 1 dispatches differ from its selection: "
+                        f"{bad}")
+    if not bool(jnp.array_equal(gen0, gen1)):
+        failures.append("tuned epoch changed the generated tokens")
+
+    # -- 4. staleness: a delayed epoch-0 writer must be refused --------------
+    from repro.core import profiles as profiles_mod
+    profiles_mod.write_manifest(live_dir, 0)
+    with warnings.catch_warnings(record=True) as wlog:
+        warnings.simplefilter("always")
+        stale_swapped = ref.poll()
+    if stale_swapped or ref.epoch != 1:
+        failures.append("stale epoch 0 manifest was adopted")
+    if not any("stale" in str(w.message) for w in wlog):
+        failures.append("stale manifest refused without a warning")
+    emit("fleet_retune/stale_epoch_refused",
+         float(not stale_swapped and ref.epoch == 1))
+
+    # -- 5. served-impl latencies -> feedback -> epoch 2 ---------------------
+    noise_rng = np.random.default_rng(1)
     rec2 = ShardRecorder("live", seed=99)
-
-    with api.tuned(store_ref=ref, plan=plan, record=rec2):
-        vec0 = jnp.asarray(plan.vector(ref))
-        gen0 = serve_pass(cfg, steps, params, prompts, args.tokens, vec0)
-        gen0.block_until_ready()
-        sizes0 = cache_sizes(steps)
-        emit("fleet_retune/plan_sites", float(len(plan)),
-             f"capacity {plan.capacity}")
-        if len(plan) == 0:
-            failures.append("no dispatch sites registered on the plan")
-
-        # publish epoch 1 (profiles first, MANIFEST last), then poll
-        rep.save(live_dir, epoch=1,
-                 source_digest=shard_digest(shard_dir))
-        swapped = ref.poll()
-        if not swapped or ref.epoch != 1:
-            failures.append(f"poll did not adopt epoch 1 "
-                            f"(swapped={swapped}, epoch={ref.epoch})")
-        vec1 = jnp.asarray(plan.vector(ref))
-        if bool(jnp.array_equal(vec0, vec1)):
-            failures.append("plan vector unchanged by the new epoch "
-                            "(no tuned selection reached a plan site)")
-        gen1 = serve_pass(cfg, steps, params, prompts, args.tokens, vec1)
-        gen1.block_until_ready()
-        sizes1 = cache_sizes(steps)
-        recompiles = sum(b - a for a, b in zip(sizes0, sizes1))
-        emit("fleet_retune/hotswap_recompilations", float(recompiles),
-             f"cache sizes {sizes0} -> {sizes1}")
-        if recompiles != 0:
-            failures.append(f"hot swap triggered {recompiles} "
-                            "recompilation(s); must be zero")
-        if not bool(jnp.array_equal(gen0, gen1)):
-            failures.append("tuned epoch changed the generated tokens")
-
-        # -- 4. staleness: a delayed epoch-0 writer must be refused ----------
-        from repro.core import profiles as profiles_mod
-        profiles_mod.write_manifest(live_dir, 0)
-        with warnings.catch_warnings(record=True) as wlog:
-            warnings.simplefilter("always")
-            stale_swapped = ref.poll()
-        if stale_swapped or ref.epoch != 1:
-            failures.append("stale epoch 0 manifest was adopted")
-        if not any("stale" in str(w.message) for w in wlog):
-            failures.append("stale manifest refused without a warning")
-        emit("fleet_retune/stale_epoch_refused",
-             float(not stale_swapped and ref.epoch == 1))
-
-        # -- 5. exploration budget -> feedback -> epoch 2 --------------------
-        ex_rng = np.random.default_rng(1)
-        vec2, explored = plan.explore(ref, eps=args.eps, rng=ex_rng)
-        vec2 = jnp.asarray(vec2)
-        serve_pass(cfg, steps, params, prompts, args.tokens,
-                   vec2).block_until_ready()
-        sizes2 = cache_sizes(steps)
-        if sizes2 != sizes1:
-            failures.append("exploration vector triggered recompilation")
-        emit("fleet_retune/explored_sites", float(len(explored)),
-             f"eps={args.eps}")
-        if args.eps >= 1.0 and len(plan) and not explored:
-            failures.append("eps=1 exploration flipped no site")
+    for r in rec_e1:
+        rec2.append(r)
+        impl = _selection(r.cell, r.phase, ref)
         # stand-in for wall clock: cost-model latency + measurement noise
-        for (cell, _ph), impl in explored.items():
-            base_t = backend.latency(cell, impl)
-            for _ in range(4):
-                rec2.observe(cell, impl,
-                             base_t * float(ex_rng.normal(1.0, 0.02)))
-        rec2.flush(shard_dir, epoch=2)
-        observed = load_shard_latencies(shard_dir)
-        if explored and not observed:
-            failures.append("exploration measurements did not round-trip "
-                            "through the shard files")
-        emit("fleet_retune/feedback_pairs", float(len(observed)))
+        base_t = backend.latency(r.cell, impl)
+        for _ in range(4):
+            rec2.observe(r.cell, impl,
+                         base_t * float(noise_rng.normal(1.0, 0.02)))
+    rec2.flush(shard_dir, epoch=2)
+    observed = load_shard_latencies(shard_dir)
+    if rec_e1 and not observed:
+        failures.append("served-impl measurements did not round-trip "
+                        "through the shard files")
+    emit("fleet_retune/feedback_pairs", float(len(observed)))
 
-        fb = tuner.FeedbackBackend(backend, observed)
-        rep2 = tuner.tune_trace(Trace.merge_shards(shard_dir).trace,
-                                backend=fb, min_win=args.min_win)
-        rep2.save(live_dir, epoch=2,
-                  source_digest=shard_digest(shard_dir))
-        if not ref.poll() or ref.epoch != 2:
-            failures.append(f"epoch 2 not adopted (epoch={ref.epoch})")
-        vec3 = jnp.asarray(plan.vector(ref))
-        serve_pass(cfg, steps, params, prompts, args.tokens,
-                   vec3).block_until_ready()
-        if cache_sizes(steps) != sizes2:
-            failures.append("epoch 2 hot swap triggered recompilation")
-        emit("fleet_retune/final_epoch", float(ref.epoch))
+    fb = tuner.FeedbackBackend(backend, observed)
+    rep2 = tuner.tune_trace(Trace.merge_shards(shard_dir).trace,
+                            backend=fb, min_win=args.min_win)
+    rep2.save(live_dir, epoch=2, source_digest=shard_digest(shard_dir))
+    if not ref.poll() or ref.epoch != 2:
+        failures.append(f"epoch 2 not adopted (epoch={ref.epoch})")
+    _, rec_e2 = live()
+    bad = _served_mismatch(rec_e2, ref)
+    if bad:
+        failures.append(f"epoch 2 dispatches differ from its selection: "
+                        f"{bad}")
+    emit("fleet_retune/final_epoch", float(ref.epoch))
 
     (out / "summary.json").write_text(json.dumps({
         "arch": cfg.name, "tp": args.tp, "topo": args.topo,
@@ -315,9 +302,8 @@ def main(argv=None) -> int:
         "merged_dispatches": fleet_trace.total(),
         "union_cost_us": {"default": cost_default * 1e6,
                           "merged": cost_merged * 1e6},
-        "plan_sites": len(plan), "explored_sites": len(explored),
+        "epoch1_tuned_dispatches": tuned_sites,
         "feedback_pairs": len(observed), "final_epoch": ref.epoch,
-        "hotswap_recompilations": recompiles,
         "failures": failures,
     }, indent=1))
 
@@ -384,9 +370,10 @@ def chaos_main(args) -> int:
     B. a manifest/profile-skewed publish is refused; the repaired
        republish (same epoch number, different manifest text — the case
        the content stamp exists for) is adopted;
-    C. a published-but-regressing epoch trips ``api.EpochTripwire``,
-       rolls back with ZERO recompilations and unchanged tokens, and the
-       poisoned epoch is refused on re-publish;
+    C. a published-but-regressing epoch trips ``EpochTripwire``, which
+       rolls back; the rebuilt steps dispatch the restored epoch's impls
+       and generate unchanged tokens, and the poisoned epoch is refused
+       on re-publish;
     D. the coordinator flags the killed server and recommends a drift
        retune whose ratio reflects the MAD-filtered fleet observations
        (latency spikes rejected, not averaged in).
@@ -395,7 +382,8 @@ def chaos_main(args) -> int:
     deterministic.
     """
     from repro.core import profiles as profiles_mod
-    from repro.core.api import DispatchRecord, EpochTripwire
+    from repro.core.api import DispatchRecord
+    from repro.core.profiles import EpochTripwire
     from repro.ft import ChaosMonkey, FleetCoordinator
 
     topo = cm.PRESETS[args.topo]
@@ -427,8 +415,7 @@ def chaos_main(args) -> int:
         rec = ShardRecorder(f"srv{i}", seed=i)
         steps = make_steps(cfg, args.tp, s_max, batch)
         with api.tuned(record=rec):
-            serve_pass(cfg, steps, params, prompts, tokens,
-                       jnp.zeros(1, jnp.int32))
+            serve_pass(cfg, steps, params, prompts, tokens)
         claims.append(rec.total())
         paths.append(rec.flush(shard_dir, epoch=1))
         clean_totals.append(Trace.load(paths[i]).total())
@@ -466,109 +453,91 @@ def chaos_main(args) -> int:
                            min_win=args.min_win)
     os.environ[PROFILE_DIR_ENV] = str(live_dir)
     ref = resolve_stores(watch=True)
-    plan = api.Plan(capacity=64)
     batch, plen = fleet[0]
     params = make_params(cfg, args.tp)
     prompts = jnp.asarray(
         rng.integers(0, cfg.vocab_size, (batch, plen)), jnp.int32)
-    steps = make_steps(cfg, args.tp, s_max, batch)
 
-    with api.tuned(store_ref=ref, plan=plan):
-        vec0 = jnp.asarray(plan.vector(ref))
-        gen0 = serve_pass(cfg, steps, params, prompts, tokens, vec0)
-        gen0.block_until_ready()
-        sizes0 = cache_sizes(steps)
+    def live():
+        return live_pass(cfg, args.tp, s_max, params, prompts, tokens, ref)
 
-        rep.save(live_dir, epoch=1, source_digest=shard_digest(shard_dir))
-        if not ref.poll() or ref.epoch != 1:
-            failures.append(f"epoch 1 not adopted (epoch={ref.epoch})")
+    rep.save(live_dir, epoch=1, source_digest=shard_digest(shard_dir))
+    if not ref.poll() or ref.epoch != 1:
+        failures.append(f"epoch 1 not adopted (epoch={ref.epoch})")
 
-        # -- B. manifest/profile skew refused; repaired republish lands ------
-        rep.save(live_dir, epoch=2, source_digest="sha256:chaos-e2")
-        monkey.skew_profiles(live_dir)
-        with warnings.catch_warnings(record=True) as wlog:
-            warnings.simplefilter("always")
-            skew_swapped = ref.poll()
-        skew_refused = (not skew_swapped and ref.epoch == 1
-                        and any("skew" in str(w.message) for w in wlog))
-        emit("fleet_chaos/manifest_skew_refused", float(skew_refused))
-        if not skew_refused:
-            failures.append("manifest/profile skew was not refused "
-                            f"(swapped={skew_swapped}, epoch={ref.epoch})")
-        rep.save(live_dir, epoch=2, source_digest="sha256:chaos-e2-fixed")
-        if not ref.poll() or ref.epoch != 2:
-            failures.append(f"repaired epoch 2 not adopted "
-                            f"(epoch={ref.epoch})")
-        vec2 = jnp.asarray(plan.vector(ref))
-        gen2 = serve_pass(cfg, steps, params, prompts, tokens, vec2)
-        gen2.block_until_ready()
+    # -- B. manifest/profile skew refused; repaired republish lands ----------
+    rep.save(live_dir, epoch=2, source_digest="sha256:chaos-e2")
+    monkey.skew_profiles(live_dir)
+    with warnings.catch_warnings(record=True) as wlog:
+        warnings.simplefilter("always")
+        skew_swapped = ref.poll()
+    skew_refused = (not skew_swapped and ref.epoch == 1
+                    and any("skew" in str(w.message) for w in wlog))
+    emit("fleet_chaos/manifest_skew_refused", float(skew_refused))
+    if not skew_refused:
+        failures.append("manifest/profile skew was not refused "
+                        f"(swapped={skew_swapped}, epoch={ref.epoch})")
+    rep.save(live_dir, epoch=2, source_digest="sha256:chaos-e2-fixed")
+    if not ref.poll() or ref.epoch != 2:
+        failures.append(f"repaired epoch 2 not adopted "
+                        f"(epoch={ref.epoch})")
+    gen2, rec_e2 = live()
 
-        # -- C. regressing epoch 3 -> tripwire rollback, zero re-jits --------
-        def live_cost():
-            return sum(tuner.estimate_trace_cost(
-                fleet_trace, backend, base=ref.base,
-                phases=ref.phases).values())
+    # -- C. regressing epoch 3 -> tripwire rollback, steps rebuilt -----------
+    def live_cost():
+        return sum(tuner.estimate_trace_cost(
+            fleet_trace, backend, base=ref.base,
+            phases=ref.phases).values())
 
-        cost_good = live_cost()
-        tw = EpochTripwire(ref, threshold=1.3, window=4, min_samples=2)
-        for _ in range(3):
-            tw.observe(cost_good)
-        bad_phases = _worst_stores(fleet_trace, backend)
-        for sub in [p for p in live_dir.iterdir() if p.is_dir()]:
-            shutil.rmtree(sub)      # epoch 3 replaces the phase stores
-        for ph, store in bad_phases.items():
-            store.save(live_dir / ph)
-        profiles_mod.write_manifest(live_dir, 3,
-                                    source_digest="sha256:chaos-e3")
-        if not ref.poll() or ref.epoch != 3:
-            failures.append(f"bad epoch 3 not adopted (epoch={ref.epoch})")
-        vec3 = jnp.asarray(plan.vector(ref))
-        serve_pass(cfg, steps, params, prompts, tokens,
-                   vec3).block_until_ready()
-        cost_bad = live_cost()
-        emit("fleet_chaos/bad_epoch_regression",
-             cost_bad / cost_good if cost_good else 0.0,
-             f"{cost_good * 1e6:.1f} -> {cost_bad * 1e6:.1f} us")
-        if cost_bad <= 1.3 * cost_good:
-            failures.append(
-                f"injected epoch 3 does not regress past the tripwire "
-                f"threshold ({cost_bad:.3e} vs {cost_good:.3e})")
-        with warnings.catch_warnings(record=True):
-            warnings.simplefilter("always")
-            fired = [tw.observe(cost_bad) for _ in range(3)]
-        emit("fleet_chaos/rollback_fired", float(any(fired)),
-             f"fired={tw.fired}")
-        if tw.fired != [(3, 2)]:
-            failures.append(f"tripwire fired {tw.fired}, expected "
-                            "[(3, 2)] (bad epoch 3 -> restored 2)")
-        vec_r = jnp.asarray(plan.vector(ref))
-        if not bool(jnp.array_equal(vec_r, vec2)):
-            failures.append("rolled-back plan vector differs from the "
-                            "restored epoch's")
-        gen_r = serve_pass(cfg, steps, params, prompts, tokens, vec_r)
-        gen_r.block_until_ready()
-        if not bool(jnp.array_equal(gen_r, gen2)):
-            failures.append("rollback changed the generated tokens")
-        # the poisoned epoch must be refused even on a fresh republish
-        profiles_mod.write_manifest(live_dir, 3,
-                                    source_digest="sha256:chaos-e3-retry")
-        with warnings.catch_warnings(record=True) as wlog:
-            warnings.simplefilter("always")
-            re_swapped = ref.poll()
-        poisoned_refused = (not re_swapped and ref.epoch == 2
-                            and any("poisoned" in str(w.message)
-                                    for w in wlog))
-        emit("fleet_chaos/poisoned_epoch_refused", float(poisoned_refused))
-        if not poisoned_refused:
-            failures.append("poisoned epoch 3 re-publish was adopted "
-                            "(or refused without a warning)")
-        recompiles = sum(b - a
-                         for a, b in zip(sizes0, cache_sizes(steps)))
-        emit("fleet_chaos/recompilations", float(recompiles),
-             "across skew + bad epoch + rollback")
-        if recompiles != 0:
-            failures.append(f"{recompiles} recompilation(s) across the "
-                            "chaos swaps; must be zero")
+    cost_good = live_cost()
+    tw = EpochTripwire(ref, threshold=1.3, window=4, min_samples=2)
+    for _ in range(3):
+        tw.observe(cost_good)
+    bad_phases = _worst_stores(fleet_trace, backend)
+    for sub in [p for p in live_dir.iterdir() if p.is_dir()]:
+        shutil.rmtree(sub)      # epoch 3 replaces the phase stores
+    for ph, store in bad_phases.items():
+        store.save(live_dir / ph)
+    profiles_mod.write_manifest(live_dir, 3,
+                                source_digest="sha256:chaos-e3")
+    if not ref.poll() or ref.epoch != 3:
+        failures.append(f"bad epoch 3 not adopted (epoch={ref.epoch})")
+    cost_bad = live_cost()
+    emit("fleet_chaos/bad_epoch_regression",
+         cost_bad / cost_good if cost_good else 0.0,
+         f"{cost_good * 1e6:.1f} -> {cost_bad * 1e6:.1f} us")
+    if cost_bad <= 1.3 * cost_good:
+        failures.append(
+            f"injected epoch 3 does not regress past the tripwire "
+            f"threshold ({cost_bad:.3e} vs {cost_good:.3e})")
+    with warnings.catch_warnings(record=True):
+        warnings.simplefilter("always")
+        fired = [tw.observe(cost_bad) for _ in range(3)]
+    emit("fleet_chaos/rollback_fired", float(any(fired)),
+         f"fired={tw.fired}")
+    if tw.fired != [(3, 2)]:
+        failures.append(f"tripwire fired {tw.fired}, expected "
+                        "[(3, 2)] (bad epoch 3 -> restored 2)")
+    # the rollback reaches dispatch when the server rebuilds its steps
+    gen_r, rec_r = live()
+    if [r.impl for r in rec_r] != [r.impl for r in rec_e2]:
+        failures.append("rolled-back dispatches differ from the restored "
+                        "epoch's")
+    if not bool(jnp.array_equal(gen_r, gen2)):
+        failures.append("rollback changed the generated tokens")
+    # the poisoned epoch must be refused even on a fresh republish
+    profiles_mod.write_manifest(live_dir, 3,
+                                source_digest="sha256:chaos-e3-retry")
+    with warnings.catch_warnings(record=True) as wlog:
+        warnings.simplefilter("always")
+        re_swapped = ref.poll()
+    poisoned_refused = (not re_swapped and ref.epoch == 2
+                        and any("poisoned" in str(w.message)
+                                for w in wlog))
+    emit("fleet_chaos/poisoned_epoch_refused", float(poisoned_refused))
+    if not poisoned_refused:
+        failures.append("poisoned epoch 3 re-publish was adopted "
+                        "(or refused without a warning)")
 
     # -- D. coordinator: killed server + MAD-robust drift retune -------------
     now = [0.0]
@@ -632,7 +601,6 @@ def chaos_main(args) -> int:
         "merged_dispatches": report.trace.total(),
         "dropped_weight": report.dropped_weight,
         "rollback_fired": tw.fired,
-        "recompilations": recompiles,
         "dead_servers": st2.dead,
         "drift": st2.drift,
         "mad_rejected": fb.rejected,

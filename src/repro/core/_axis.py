@@ -6,14 +6,8 @@ multi-host-device shard_map tests, and (c) the production mesh lowering.
 """
 from __future__ import annotations
 
-import contextlib
-import threading
-
-import jax
 import jax.numpy as jnp
 from jax import lax
-
-_TLS = threading.local()
 
 
 def axis_size(axis_name: str) -> int:
@@ -39,38 +33,6 @@ def shift_perm(p: int, shift: int) -> list[tuple[int, int]]:
     return [(i, i + shift) for i in range(-shift, p)]
 
 
-def axis_is_vmapped(axis_name: str) -> bool:
-    """True when ``axis_name`` is bound by a vmap ``BatchTrace`` in the
-    CURRENT trace chain (as opposed to a shard_map mesh axis).  Callers
-    that defer collective tracing (``lax.switch`` branches — the runtime
-    dispatch plans) must ask here, at the call site: inside the branch
-    the chain is cut and the answer is unknowable."""
-    from jax._src import core as _core
-    t = getattr(_core.trace_ctx, "trace", None)
-    while t is not None:
-        data = getattr(t, "axis_data", None)
-        if (type(t).__name__ == "BatchTrace" and data is not None
-                and data.name == axis_name):
-            return True
-        t = getattr(t, "parent_trace", None)
-    return False
-
-
-@contextlib.contextmanager
-def force_full_perm(axis_names):
-    """Make ``pshift`` over these axes emit COMPLETE permutations for the
-    duration.  Needed around deferred tracing (``lax.switch`` branches)
-    of a vmap-emulated axis: the batching rule that rejects partial perms
-    runs after ``pshift``'s own try/except has returned, so the proactive
-    padding must be requested from outside."""
-    prev = getattr(_TLS, "full_perm_axes", frozenset())
-    _TLS.full_perm_axes = prev | frozenset(axis_names)
-    try:
-        yield
-    finally:
-        _TLS.full_perm_axes = prev
-
-
 def pshift(x, axis_name: str, pairs: list[tuple[int, int]]):
     """``lax.ppermute`` that accepts *partial* permutations everywhere.
 
@@ -84,11 +46,10 @@ def pshift(x, axis_name: str, pairs: list[tuple[int, int]]):
     p = axis_size(axis_name)
     if len(pairs) == p:
         return lax.ppermute(x, axis_name, pairs)
-    if axis_name not in getattr(_TLS, "full_perm_axes", frozenset()):
-        try:
-            return lax.ppermute(x, axis_name, pairs)
-        except AssertionError:
-            pass
+    try:
+        return lax.ppermute(x, axis_name, pairs)
+    except AssertionError:
+        pass
     srcs = {s for s, _ in pairs}
     dsts = {d for _, d in pairs}
     free_s = [i for i in range(p) if i not in srcs]

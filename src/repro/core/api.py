@@ -18,12 +18,6 @@ O(log M) binary search runs while tracing and the compiled program contains
 only the winning algorithm — zero runtime overhead (an improvement over the
 paper's runtime hash+bsearch, see DESIGN.md §2).
 
-Fleet hot-swap is the exception: ``tuned(plan=Plan(), store_ref=ref)``
-switches eligible sites to RUNTIME dispatch — the trace emits
-``lax.switch`` over every admissible impl and reads the branch index from
-a traced plan vector (``plan_input``), so a new profile epoch changes the
-vector's CONTENTS, never the compiled program: zero re-jits on swap.
-
 The context also carries the scratch budget (the paper's
 ``size_msg_buffer_bytes``): a mock-up whose Table-1 extra memory exceeds the
 budget is not applied, exactly like PGMPITuneLib refusing replacements when
@@ -86,10 +80,8 @@ class TuneContext:
     record: list[DispatchRecord] = dataclasses.field(default_factory=list)
     chunk_bytes: int = 0
     phase_profiles: dict[str, ProfileStore] | None = None
-    # fleet retuning: live hot-swappable stores (profiles.StoreRef) and
-    # the runtime-dispatch plan (api.Plan) — see module docstring
+    # fleet retuning: the live hot-swappable stores (profiles.StoreRef)
     store_ref: object | None = None
-    plan: "Plan | None" = None
     # per-axis interconnect map (costmodel.MeshTopo): stamps each
     # dispatched cell's tier token so profiles / traces key by tier
     mesh_topo: object | None = None
@@ -150,7 +142,6 @@ def tuned(profiles: ProfileStore | None = None,
           phase_profiles: dict[str, ProfileStore] | None = None,
           record: list | None = None,
           store_ref=None,
-          plan: "Plan | None" = None,
           mesh_topo=None):
     """Activate tuning for every ``repro.core.api`` collective issued inside.
 
@@ -166,10 +157,8 @@ def tuned(profiles: ProfileStore | None = None,
     Fleet mode: ``store_ref`` (a ``profiles.StoreRef``) is consulted
     after the explicit stores and read LIVE — swapping a new epoch into
     the ref changes what later jit traces select without rebuilding the
-    context.  ``plan`` additionally switches eligible sites to runtime
-    dispatch (``lax.switch`` over admissible impls, branch index from the
-    ``plan_input`` vector), so a swap takes effect in ALREADY-COMPILED
-    steps with zero re-jits.
+    context.  An executable already compiled keeps the impls it was
+    traced with; a new epoch reaches a step at its next trace.
     """
     prev = _ctx()
     ctx = TuneContext(profiles=profiles, force=dict(force or {}),
@@ -178,7 +167,7 @@ def tuned(profiles: ProfileStore | None = None,
                       phase_profiles=(dict(phase_profiles)
                                       if phase_profiles else None),
                       record=record if record is not None else [],
-                      store_ref=store_ref, plan=plan, mesh_topo=mesh_topo)
+                      store_ref=store_ref, mesh_topo=mesh_topo)
     _TLS.ctx = ctx
     try:
         yield ctx
@@ -336,257 +325,11 @@ def _select(op: str, payload, axis: str, impl: str | None, kw) -> str:
     return name
 
 
-# ---------------------------------------------------------------------------
-# runtime dispatch plans (fleet hot-swap; DESIGN_TRACE.md "epochal hot-swap")
-# ---------------------------------------------------------------------------
-
-#: recorded impl marker for sites dispatched through a runtime plan — the
-#: branch taken is decided per call by the plan vector, not at trace time
-PLAN_IMPL = "plan"
-
-
-class Plan:
-    """A runtime dispatch plan: the fixed-capacity impl-index vector that
-    makes profile hot-swaps take effect WITHOUT a re-jit.
-
-    Static dispatch bakes the chosen impl into the jit trace, so a new
-    profile epoch would need a re-trace to matter.  Under a Plan, each
-    eligible dispatch site instead emits ``lax.switch`` over its full
-    admissible impl list and reads the branch index out of a traced int32
-    vector the step function feeds in (``plan_input``).  The vector's
-    SHAPE is the fixed ``capacity`` — it never changes, so neither does
-    the compiled program; its CONTENTS are re-derived from the live
-    stores (``vector(ref)``) whenever an epoch lands.
-
-    Sites are keyed ``(cell, phase)``: later recompilations (new shapes,
-    donation misses) re-register existing sites onto their stable slots
-    and allocate fresh slots for new cells from the spare capacity.  When
-    capacity runs out (or an op's admissible set collapses to just the
-    default) the site falls back to ordinary static dispatch — graceful,
-    and visible via ``len(plan)`` vs ``plan.capacity``.
-    """
-
-    def __init__(self, capacity: int = 128):
-        self.capacity = int(capacity)
-        self._sites: dict[tuple[OpCell, str],
-                          tuple[int, tuple[str, ...]]] = {}
-
-    def __len__(self) -> int:
-        return len(self._sites)
-
-    def slot(self, cell: OpCell, phase: str,
-             impls: tuple[str, ...]) -> int | None:
-        """Stable vector slot for a dispatch site (None = dispatch
-        statically: capacity exhausted, or the admissible set drifted
-        from what this site was registered with)."""
-        key = (cell, phase)
-        hit = self._sites.get(key)
-        if hit is not None:
-            s, known = hit
-            return s if known == impls else None
-        if len(self._sites) >= self.capacity:
-            return None
-        s = len(self._sites)
-        self._sites[key] = (s, impls)
-        return s
-
-    def sites(self) -> list[tuple[OpCell, str, tuple[str, ...]]]:
-        return [(cell, ph, impls) for (cell, ph), (_s, impls)
-                in sorted(self._sites.items(), key=lambda kv: kv[1][0])]
-
-    def _resolve(self, cell, ph, store_ref, base, phases):
-        if store_ref is not None:
-            return store_ref.lookup(cell, ph)
-        store = (phases or {}).get(ph)
-        name = store.lookup_cell(cell) if store is not None else None
-        if name is None and base is not None:
-            name = base.lookup_cell(cell)
-        return name
-
-    def vector(self, store_ref=None, *, base: ProfileStore | None = None,
-               phases: dict[str, ProfileStore] | None = None):
-        """The plan vector for the CURRENT profile generation: slot i
-        holds the index (into that site's admissible impl list, 0 =
-        default) the live stores select.  Unregistered slots stay 0."""
-        import numpy as np
-        vec = np.zeros(self.capacity, dtype=np.int32)
-        for (cell, ph), (s, impls) in self._sites.items():
-            name = self._resolve(cell, ph, store_ref, base, phases)
-            if name in impls:
-                vec[s] = impls.index(name)
-        return vec
-
-    def explore(self, store_ref=None, *, eps: float, rng,
-                base: ProfileStore | None = None,
-                phases: dict[str, ProfileStore] | None = None):
-        """The exploration-budget vector: start from ``vector(...)`` and,
-        per site, with probability ``eps`` flip to the runner-up impl —
-        the next entry in the site's admissible ring (profiles only store
-        winners, so "next" stands in for second-best; for default-serving
-        sites that is the first mock-up).  Returns ``(vec, explored)``
-        where ``explored`` maps ``(cell, phase) -> impl`` for the flipped
-        sites, so the serve loop can attribute the latencies it measures
-        (``ShardRecorder.observe``) to what actually ran."""
-        vec = self.vector(store_ref, base=base, phases=phases)
-        explored: dict[tuple[OpCell, str], str] = {}
-        for (cell, ph), (s, impls) in sorted(self._sites.items(),
-                                             key=lambda kv: kv[1][0]):
-            if len(impls) < 2 or float(rng.random()) >= eps:
-                continue
-            vec[s] = (int(vec[s]) + 1) % len(impls)
-            explored[(cell, ph)] = impls[vec[s]]
-        return vec, explored
-
-
-@contextlib.contextmanager
-def plan_input(vec):
-    """Expose the enclosing step function's traced plan-vector argument
-    to dispatch sites (builders wrap the model call in this; the vector
-    itself must be an ARGUMENT of the jitted function — a closed-over
-    array would be baked in as a constant and defeat the hot swap)."""
-    prev = getattr(_TLS, "plan_vec", None)
-    _TLS.plan_vec = vec
-    try:
-        yield
-    finally:
-        _TLS.plan_vec = prev
-
-
-class EpochTripwire:
-    """Plan-level auto-rollback: revert a freshly adopted epoch whose
-    OBSERVED cost regresses past the prior epoch's.
-
-    The tuner's staleness/digest guards stop bad *publishes*; nothing on
-    the read side stops a *well-formed but wrong* epoch — profiles tuned
-    from poisoned measurements that make every step slower.  The tripwire
-    closes that hole at the one place regression is observable: the serve
-    loop's per-step cost.  Feed it each step's observed cost (wall-clock
-    delta, or the modeled cost the bench synthesizes) via ``observe``;
-    it buckets costs by the ``StoreRef``'s live epoch, takes the median
-    of a finished epoch's window as the next epoch's baseline, and when
-    the current epoch's windowed median exceeds ``threshold ×`` baseline
-    it calls ``ref.rollback()`` — vector contents only, zero re-jit,
-    and the bad epoch is poisoned against re-adoption.
-
-    The window is a deque of the last ``window`` costs; medians make a
-    single exploration spike or latency outlier unable to trip it (the
-    same robustness argument as ``tuner.FeedbackBackend``'s MAD filter).
-    """
-
-    def __init__(self, ref, *, threshold: float = 1.5, window: int = 8,
-                 min_samples: int = 4):
-        self.ref = ref
-        self.threshold = float(threshold)
-        self.window = int(window)
-        self.min_samples = int(min_samples)
-        self._epoch = ref.epoch
-        self._costs: list[float] = []
-        self._baseline: float | None = None   # prior epoch's median cost
-        self.fired: list[tuple[int, int]] = []  # (bad epoch, restored)
-
-    @property
-    def baseline(self) -> float | None:
-        return self._baseline
-
-    def observe(self, cost: float) -> bool:
-        """Record one observed step cost under the CURRENT live epoch;
-        returns True iff this observation fired a rollback."""
-        import statistics
-        epoch = self.ref.epoch
-        if epoch != self._epoch:
-            if epoch > self._epoch and len(self._costs) >= self.min_samples:
-                # the finished epoch's steady-state cost becomes the new
-                # epoch's yardstick
-                self._baseline = statistics.median(self._costs)
-            # on epoch < self._epoch (a rollback we didn't fire) the
-            # baseline stays: it IS the restored epoch's own median
-            self._costs = []
-            self._epoch = epoch
-        self._costs.append(float(cost))
-        del self._costs[:-self.window]
-        if self._baseline is None or len(self._costs) < self.min_samples:
-            return False
-        med = statistics.median(self._costs)
-        if med <= self.threshold * self._baseline:
-            return False
-        restored = self.ref.rollback()
-        if restored is None:
-            return False   # nothing retained; keep serving + observing
-        self.fired.append((epoch, restored))
-        self._epoch = restored
-        self._costs = []
-        return True
-
-
-def _admissible_impls(op: str, cell: OpCell,
-                      ctx: TuneContext) -> tuple[str, ...]:
-    """The impls a runtime plan may switch between for one site, in a
-    deterministic order (default first) — the same §4.2 admission rules
-    static dispatch applies (pow2 guard, Table-1 scratch budget), which
-    only depend on the static cell, never on the profile choice."""
-    reg = C.REGISTRY[op]
-    p, nbytes = cell.p, cell.nbytes
-    out = []
-    for name in ["default"] + sorted(n for n in reg if n != "default"):
-        impl = reg[name]
-        if impl.requires_pow2 and (
-                (p & (p - 1)) != 0
-                or (cell.p2 and (cell.p2 & (cell.p2 - 1)) != 0)):
-            continue
-        if name != "default" and getattr(impl, "hier", False) != cell.hier:
-            continue
-        if name != "default" and C.is_demoted(op, name):
-            continue
-        if (ctx.scratch_budget_bytes is not None and name != "default"
-                and impl.extra_bytes(nbytes, p) > ctx.scratch_budget_bytes):
-            continue
-        out.append(name)
-    return tuple(out)
-
-
-_NO_PLAN = object()
-
-
-def _dispatch_plan(op: str, payload, axis: str, ctx: TuneContext,
-                   plan_vec, kw):
-    """Emit the runtime-dispatch form of one site: ``lax.switch`` over
-    the admissible impls, branch index read from the plan vector.
-    Returns ``_NO_PLAN`` when the site must dispatch statically."""
-    cell = _make_cell(op, payload, axis, kw)
-    impls = _admissible_impls(op, cell, ctx)
-    if len(impls) < 2:
-        return _NO_PLAN
-    slot = ctx.plan.slot(cell, current_phase(), impls)
-    if slot is None:
-        return _NO_PLAN
-    ctx.record.append(DispatchRecord(cell, PLAN_IMPL, current_phase()))
-    import jax.numpy as jnp
-    from jax import lax
-    from repro.core._axis import axis_is_vmapped, force_full_perm
-    idx = jnp.clip(plan_vec[slot], 0, len(impls) - 1)
-    reg = C.REGISTRY[op]
-    branches = [(lambda f: (lambda _: f(payload, axis, **kw)))(reg[n].fn)
-                for n in impls]
-    # switch branches trace deferred, past pshift's own partial-perm
-    # fallback — vmap-emulated axes must be told to pad proactively
-    axes = [a for a in (axis, kw.get("rs_axis"))
-            if isinstance(a, str) and axis_is_vmapped(a)]
-    with force_full_perm(axes), jax.named_scope(f"pgtune.{op}.{PLAN_IMPL}"):
-        return lax.switch(idx, branches, 0)
-
-
 def _dispatch(op: str, payload, axis: str, impl: str | None, /, **kw):
     ctx = _ctx()
     if ctx is not None and ctx.chunk_bytes and "chunk" not in kw:
         itemsize = payload.dtype.itemsize
         kw["chunk"] = max(1, ctx.chunk_bytes // itemsize)
-    if impl is None and ctx is not None and ctx.plan is not None:
-        plan_vec = getattr(_TLS, "plan_vec", None)
-        if (plan_vec is not None and op not in ctx.force
-                and op not in _env_force()):
-            out = _dispatch_plan(op, payload, axis, ctx, plan_vec, kw)
-            if out is not _NO_PLAN:
-                return out
     name = _select(op, payload, axis, impl, kw)
     with jax.named_scope(f"pgtune.{op}.{name}"):
         return C.REGISTRY[op][name].fn(payload, axis, **kw)

@@ -1075,8 +1075,8 @@ OPS = tuple(REGISTRY.keys())
 # A quantized-wire impl whose numeric error exceeds its wire tolerance on a
 # representative payload (core/selfcheck.py) is demoted here and from then on
 # is treated exactly like a failed guideline: api._select falls back to the
-# default, api._admissible_impls / tuner skip it, plan vectors never carry
-# it.  Process-local state, keyed (op, impl name).
+# default and the tuner skips it.  Process-local state, keyed (op, impl
+# name).
 # ---------------------------------------------------------------------------
 
 _DEMOTED: dict[tuple[str, str], str] = {}

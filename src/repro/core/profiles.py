@@ -468,9 +468,10 @@ class StoreRef:
     stores plus their epoch — the hot-swap unit of fleet retuning.
 
     ``api.tuned(store_ref=ref)`` contexts read impl choices through the
-    ref at dispatch time, and ``api.Plan.vector(ref)`` re-derives runtime
-    dispatch plans from it — so swapping in a new generation changes what
-    a running server serves WITHOUT a re-jit.  State is one tuple
+    ref at dispatch time, which is trace time: swapping in a new
+    generation changes what the NEXT trace of a step selects, and an
+    executable already compiled keeps the impls it was traced with.
+    State is one tuple
     attribute assigned in a single store, so readers never observe a
     half-swapped generation.  ``swap`` refuses epochs older than the live
     one (the staleness rule: a delayed writer must not roll a fleet
@@ -479,7 +480,7 @@ class StoreRef:
 
     Fault tolerance: the last ``history`` adopted generations are
     RETAINED in memory, so ``rollback()`` can revert a regressing epoch
-    without touching disk (the ``api.EpochTripwire`` path).  A rolled-
+    without touching disk (the ``EpochTripwire`` path).  A rolled-
     back epoch is POISONED — ``poll``/``swap`` refuse to re-adopt it even
     though its manifest is still the newest on disk — and adoption
     verifies the manifest's ``profiles_digest`` against the profile
@@ -556,9 +557,9 @@ class StoreRef:
         field.  The abandoned epoch is POISONED (never re-adopted by
         ``poll`` even though its manifest still looks newest) and the
         previous generation's stores become live again in one atomic
-        assignment: readers and ``Plan.vector`` re-derivation see the
-        reverted generation immediately, with zero re-jits.  Returns the
-        restored epoch, or None when no history is retained."""
+        assignment: every later lookup, and so every later trace, sees
+        the reverted generation.  Returns the restored epoch, or None
+        when no history is retained."""
         import warnings
         if not self._history:
             warnings.warn("StoreRef.rollback: no retained generation to "
@@ -661,6 +662,74 @@ class StoreRef:
         return True
 
 
+class EpochTripwire:
+    """Epoch auto-rollback: revert a freshly adopted epoch whose OBSERVED
+    cost regresses past the prior epoch's.
+
+    The tuner's staleness/digest guards stop bad *publishes*; nothing on
+    the read side stops a *well-formed but wrong* epoch — profiles tuned
+    from poisoned measurements that make every step slower.  The tripwire
+    closes that hole at the one place regression is observable: the serve
+    loop's per-step cost.  Feed it each step's observed cost (wall-clock
+    delta, or the modeled cost the bench synthesizes) via ``observe``;
+    it buckets costs by the ``StoreRef``'s live epoch, takes the median
+    of a finished epoch's window as the next epoch's baseline, and when
+    the current epoch's windowed median exceeds ``threshold ×`` baseline
+    it calls ``ref.rollback()``, and the bad epoch is poisoned against
+    re-adoption.  Dispatch reads the ref at trace time, so the rollback
+    takes effect at the next trace of a step: a server rebuilds (or
+    re-jits) its step functions once the tripwire has fired.
+
+    The window is a deque of the last ``window`` costs; medians make a
+    single latency spike unable to trip it (the same robustness argument
+    as ``tuner.FeedbackBackend``'s MAD filter).
+    """
+
+    def __init__(self, ref, *, threshold: float = 1.5, window: int = 8,
+                 min_samples: int = 4):
+        self.ref = ref
+        self.threshold = float(threshold)
+        self.window = int(window)
+        self.min_samples = int(min_samples)
+        self._epoch = ref.epoch
+        self._costs: list[float] = []
+        self._baseline: float | None = None   # prior epoch's median cost
+        self.fired: list[tuple[int, int]] = []  # (bad epoch, restored)
+
+    @property
+    def baseline(self) -> float | None:
+        return self._baseline
+
+    def observe(self, cost: float) -> bool:
+        """Record one observed step cost under the CURRENT live epoch;
+        returns True iff this observation fired a rollback."""
+        import statistics
+        epoch = self.ref.epoch
+        if epoch != self._epoch:
+            if epoch > self._epoch and len(self._costs) >= self.min_samples:
+                # the finished epoch's steady-state cost becomes the new
+                # epoch's yardstick
+                self._baseline = statistics.median(self._costs)
+            # on epoch < self._epoch (a rollback we didn't fire) the
+            # baseline stays: it IS the restored epoch's own median
+            self._costs = []
+            self._epoch = epoch
+        self._costs.append(float(cost))
+        del self._costs[:-self.window]
+        if self._baseline is None or len(self._costs) < self.min_samples:
+            return False
+        med = statistics.median(self._costs)
+        if med <= self.threshold * self._baseline:
+            return False
+        restored = self.ref.rollback()
+        if restored is None:
+            return False   # nothing retained; keep serving + observing
+        self.fired.append((epoch, restored))
+        self._epoch = restored
+        self._costs = []
+        return True
+
+
 # ---------------------------------------------------------------------------
 # directory / environment resolution (serve + train consumers)
 # ---------------------------------------------------------------------------
@@ -705,10 +774,9 @@ def resolve_stores(directory: str | pathlib.Path | None = None, *,
     resolved directory's current generation (epoch from ``MANIFEST.json``;
     0 for a legacy manifest-less directory; -1 when nothing is loadable
     yet), watching the directory — call ``ref.poll()`` periodically to
-    pick up new epochs, and hand the ref to ``api.tuned(store_ref=...)``
-    / ``api.Plan.vector(ref)``.  A missing-or-empty directory is NOT an
-    error in watch mode: the ref starts empty and the first poll after a
-    push adopts it.
+    pick up new epochs, and hand the ref to ``api.tuned(store_ref=...)``.
+    A missing-or-empty directory is NOT an error in watch mode: the ref
+    starts empty and the first poll after a push adopts it.
     """
     if watch:
         d = directory or os.environ.get(PROFILE_DIR_ENV, "")

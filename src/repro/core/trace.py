@@ -569,10 +569,10 @@ class ShardRecorder:
         else:
             self.dropped += 1
 
-    # -- exploration feedback ------------------------------------------------
+    # -- latency feedback ----------------------------------------------------
     def observe(self, cell: OpCell, impl: str, latency_s: float) -> None:
         """Feed one live latency measurement for (cell, impl) — the
-        exploration budget's signal back into the next epoch."""
+        served impls' signal back into the next epoch."""
         key = (cell, impl)
         n = self._lat_n.get(key, 0) + 1
         self._lat_n[key] = n
@@ -656,7 +656,7 @@ def load_shard_latencies(directory: str | pathlib.Path, *,
                          pattern: str = "shard-*.jsonl",
                          skip: "Iterable[str | pathlib.Path]" = ()) \
         -> dict[tuple[OpCell, str], list[float]]:
-    """All exploration measurements across a fleet's shard files:
+    """All latency measurements across a fleet's shard files:
     ``(cell, impl) -> [latency_s, ...]`` (samples concatenated across
     servers; feed to ``tuner.FeedbackBackend``).
 

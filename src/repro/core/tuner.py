@@ -485,7 +485,7 @@ def tune_trace(trace, backend=None, *, min_win: float = 0.10,
 
 
 # ---------------------------------------------------------------------------
-# fleet feedback (exploration-budget measurements -> next epoch's tuner)
+# fleet feedback (served-impl latency measurements -> next epoch's tuner)
 # ---------------------------------------------------------------------------
 
 
@@ -507,15 +507,16 @@ def _mad_filter(samples: list[float], k: float) -> list[float]:
 class FeedbackBackend:
     """A backend that prefers LIVE fleet measurements over its base estimate.
 
-    The exploration budget (``Plan.explore`` + ``ShardRecorder.observe``)
-    deposits real ``(cell, impl, latency)`` samples into the trace shards;
-    ``trace.load_shard_latencies`` collects them across the fleet.  Wrapping
-    the next epoch's tuner backend in this class makes ``tune_trace`` price
-    any (cell, impl) with enough observed samples from the fleet's own wall
-    clock — the loop that lets profiles track hardware/load drift — while
-    everything unexplored still falls back to the base backend.
+    Servers time the impls their traced steps dispatched and deposit
+    ``(cell, impl, latency)`` samples into their trace shards
+    (``ShardRecorder.observe``); ``trace.load_shard_latencies`` collects
+    them across the fleet.  Wrapping the next epoch's tuner backend in this
+    class makes ``tune_trace`` price any (cell, impl) with enough observed
+    samples from the fleet's own wall clock — the loop that lets profiles
+    track hardware/load drift — while every unobserved pair still falls
+    back to the base backend.
 
-    Fleet measurements are HOSTILE inputs: one explored step that landed
+    Fleet measurements are HOSTILE inputs: one timed step that landed
     on a network hiccup can be 100× the true latency, and with only a
     handful of samples per (cell, impl) even a median shifts.  Samples
     are therefore filtered at construction with median/MAD outlier
@@ -543,7 +544,7 @@ class FeedbackBackend:
 
     @property
     def supported_axis_size(self) -> int | None:
-        # cells WITH observations need no replay, but unexplored cells
+        # cells WITH observations need no replay, but unobserved cells
         # still hit the base backend, so its replay constraint stands
         return getattr(self.base, "supported_axis_size", None)
 

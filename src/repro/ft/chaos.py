@@ -116,7 +116,7 @@ class ChaosMonkey:
                         factor: float = 100.0,
                         per_line: int = 1) -> int:
         """Multiply ``per_line`` random samples in each ``#@lat``
-        reservoir by ``factor`` — the exploration step that landed on a
+        reservoir by ``factor`` — the timed step that landed on a
         network hiccup.  The shard stays VALID (digest recomputed): the
         point is that ``FeedbackBackend``'s MAD filter, not quarantine,
         must absorb these.  Returns the number of spiked samples."""

@@ -14,19 +14,17 @@ When no tuning inputs are given the step functions run under whatever
 callers that manage their own context keep full control.
 
 Fleet mode: pass ``store_ref=`` (a ``profiles.StoreRef``, e.g. from
-``resolve_stores(watch=True)``) and ``plan=`` (an ``api.Plan``).  The step
-then takes one TRAILING replicated argument — the plan vector — and every
-multi-impl dispatch site compiles to a runtime switch read from it.  A new
-profile epoch is adopted by feeding ``plan.vector(store_ref)`` on the next
-step call: contents change, shape doesn't, so the jit cache stays warm
-(zero re-trace — the hot-swap demo in bench_fleet_retune.py counts).
+``resolve_stores(watch=True)``); dispatch reads it after the explicit
+stores.  A ``StoreRef`` epoch reaches a step when the step is traced: a
+server that adopts an epoch (``ref.poll()``, or a tripwire rollback)
+rebuilds or re-jits its step functions, and an executable already
+compiled keeps the impls it was traced with.
 """
 from __future__ import annotations
 
 import contextlib
 
 import jax
-import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
@@ -46,11 +44,11 @@ def _resolve(profiles, phase_profiles, profile_dir):
 
 @contextlib.contextmanager
 def _serving_ctx(tag, profiles, phase_profiles, force, record,
-                 store_ref=None, plan=None):
+                 store_ref=None):
     """Phase-tag the step; open a tuned context only when the builder was
     given tuning inputs (else the caller's ambient context applies)."""
-    if (profiles, phase_profiles, force, store_ref,
-            plan) == (None, None, None, None, None):
+    if (profiles, phase_profiles, force,
+            store_ref) == (None, None, None, None):
         if record is None:
             with api.phase(tag):
                 yield
@@ -64,55 +62,41 @@ def _serving_ctx(tag, profiles, phase_profiles, force, record,
                            force=amb.force or None,
                            scratch_budget_bytes=amb.scratch_budget_bytes,
                            chunk_bytes=amb.chunk_bytes,
-                           store_ref=amb.store_ref, plan=amb.plan,
+                           store_ref=amb.store_ref,
                            record=record), api.phase(tag):
                 yield
             return
     with api.tuned(profiles=profiles, phase_profiles=phase_profiles,
-                   force=force, record=record, store_ref=store_ref,
-                   plan=plan), api.phase(tag):
+                   force=force, record=record,
+                   store_ref=store_ref), api.phase(tag):
         yield
 
 
 def build_prefill(cfg: ModelConfig, mesh, cell, *, profiles=None,
                   force=None, phase_profiles=None, profile_dir=None,
-                  record=None, store_ref=None, plan=None):
+                  record=None, store_ref=None):
     from repro.launch.shapes import input_specs
 
     profiles, phase_profiles = _resolve(profiles, phase_profiles,
                                         profile_dir)
     (p_sds, b_sds, c_sds), (p_ps, b_ps, c_ps) = input_specs(cfg, cell, mesh)
 
-    if plan is None:
-        def fn(params, batch, caches):
-            with _serving_ctx("prefill", profiles, phase_profiles, force,
-                              record, store_ref):
-                logits, new_caches = lm.prefill(params, cfg, batch, caches,
-                                                seq_sharded=cell.seq_sharded)
-            return logits, new_caches
+    def fn(params, batch, caches):
+        with _serving_ctx("prefill", profiles, phase_profiles, force,
+                          record, store_ref):
+            logits, new_caches = lm.prefill(params, cfg, batch, caches,
+                                            seq_sharded=cell.seq_sharded)
+        return logits, new_caches
 
-        in_specs, extra_sds = (p_ps, b_ps, c_ps), ()
-    else:
-        def fn(params, batch, caches, plan_vec):
-            with _serving_ctx("prefill", profiles, phase_profiles, force,
-                              record, store_ref, plan), \
-                    api.plan_input(plan_vec):
-                logits, new_caches = lm.prefill(params, cfg, batch, caches,
-                                                seq_sharded=cell.seq_sharded)
-            return logits, new_caches
-
-        in_specs = (p_ps, b_ps, c_ps, P())
-        extra_sds = (jax.ShapeDtypeStruct((plan.capacity,), jnp.int32),)
-
-    sm = shard_map(fn, mesh=mesh, in_specs=in_specs,
+    sm = shard_map(fn, mesh=mesh, in_specs=(p_ps, b_ps, c_ps),
                    out_specs=(P(_dp(mesh, cell)), c_ps),
                    check_vma=False)
-    return jax.jit(sm), (p_sds, b_sds, c_sds, *extra_sds)
+    return jax.jit(sm), (p_sds, b_sds, c_sds)
 
 
 def build_decode(cfg: ModelConfig, mesh, cell, *, profiles=None, force=None,
                  phase_profiles=None, profile_dir=None, record=None,
-                 store_ref=None, plan=None):
+                 store_ref=None):
     from repro.launch.shapes import input_specs
 
     profiles, phase_profiles = _resolve(profiles, phase_profiles,
@@ -120,31 +104,18 @@ def build_decode(cfg: ModelConfig, mesh, cell, *, profiles=None, force=None,
     (p_sds, t_sds, c_sds, i_sds), (p_ps, t_ps, c_ps, i_ps) = \
         input_specs(cfg, cell, mesh)
 
-    if plan is None:
-        def fn(params, token, caches, t):
-            with _serving_ctx("decode", profiles, phase_profiles, force,
-                              record, store_ref):
-                return lm.decode_step(params, cfg, token, caches, t,
-                                      seq_sharded=cell.seq_sharded)
+    def fn(params, token, caches, t):
+        with _serving_ctx("decode", profiles, phase_profiles, force,
+                          record, store_ref):
+            return lm.decode_step(params, cfg, token, caches, t,
+                                  seq_sharded=cell.seq_sharded)
 
-        in_specs, extra_sds = (p_ps, t_ps, c_ps, i_ps), ()
-    else:
-        def fn(params, token, caches, t, plan_vec):
-            with _serving_ctx("decode", profiles, phase_profiles, force,
-                              record, store_ref, plan), \
-                    api.plan_input(plan_vec):
-                return lm.decode_step(params, cfg, token, caches, t,
-                                      seq_sharded=cell.seq_sharded)
-
-        in_specs = (p_ps, t_ps, c_ps, i_ps, P())
-        extra_sds = (jax.ShapeDtypeStruct((plan.capacity,), jnp.int32),)
-
-    sm = shard_map(fn, mesh=mesh, in_specs=in_specs,
+    sm = shard_map(fn, mesh=mesh, in_specs=(p_ps, t_ps, c_ps, i_ps),
                    out_specs=(t_ps if cell.seq_sharded
                               else P(_dp(mesh, cell)), c_ps),
                    check_vma=False)
     return (jax.jit(sm, donate_argnums=(2,)),
-            (p_sds, t_sds, c_sds, i_sds, *extra_sds))
+            (p_sds, t_sds, c_sds, i_sds))
 
 
 def _dp(mesh, cell):

@@ -16,8 +16,8 @@ instruction compiled inside them, so they cost nothing at run time:
   and the inter-chunk output) nested inside, and ``ssd`` (Mamba2's
   chunked scan), in ``models/ssm.py``;
 * ``grad_sync`` and ``optimizer`` in ``train/trainer.py``;
-* ``pgtune.<op>.<impl>`` at each collective the dispatcher emits
-  (``pgtune.<op>.plan`` for a runtime plan's switch), ``core/api.py``.
+* ``pgtune.<op>.<impl>`` at each collective the dispatcher emits,
+  ``core/api.py``.
 
 Autodiff wraps a scope entered outside a loop body as ``jvp(head)`` and
 ``transpose(jvp(head))``; a scope inside a scanned body stays bare.

@@ -13,11 +13,11 @@ import warnings
 import pytest
 
 from repro.core import api
-from repro.core.api import EpochTripwire
 from repro.core.cell import OpCell
-from repro.core.profiles import (MANIFEST_NAME, Profile, ProfileStore,
-                                 Range, StoreRef, profiles_digest,
-                                 read_manifest, write_manifest)
+from repro.core.profiles import (MANIFEST_NAME, EpochTripwire, Profile,
+                                 ProfileStore, Range, StoreRef,
+                                 profiles_digest, read_manifest,
+                                 write_manifest)
 from repro.core.trace import (ShardRecorder, Trace, load_shard_latencies,
                               shard_meta)
 from repro.core.tuner import CostModelBackend, FeedbackBackend, _mad_filter

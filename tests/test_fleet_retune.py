@@ -1,11 +1,11 @@
-"""Fleet-scale online retuning: shard recording, epochal profiles,
-store-ref hot swap, and runtime dispatch plans.
+"""Fleet-scale online retuning: shard recording, epochal profiles and
+the store-ref hot swap.
 
-Covers the ISSUE-7 tentpole end to end at unit scale: bounded per-server
+Covers the fleet loop at unit scale: bounded per-server
 ``ShardRecorder``s, weight-preserving ``Trace.merge_shards``, MANIFEST
-epochs with the staleness rule, and the zero-re-jit hot swap (a jitted
-step's impl choice provably changes at RUNTIME through the plan vector
-while the jit cache stays at one entry).
+epochs with the staleness rule, the dispatcher's selection order, and a
+swapped epoch reaching the next trace of a step (an executable already
+compiled keeps the impl it was traced with).
 """
 import json
 import warnings
@@ -291,73 +291,51 @@ def test_resolve_stores_watch_mode_unset_env(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Plan: stable slots, capacity, vectors, exploration
-# ---------------------------------------------------------------------------
-
-
-def test_plan_slots_stable_across_reregistration():
-    plan = api.Plan(capacity=8)
-    cell = OpCell("allreduce", 4, 512)
-    impls = ("default", "a", "b")
-    s = plan.slot(cell, "fwd", impls)
-    assert plan.slot(cell, "fwd", impls) == s      # recompilation: same slot
-    assert plan.slot(cell, "bwd", impls) == s + 1  # new phase: new site
-    # admissible-set drift disables the site rather than mis-indexing
-    assert plan.slot(cell, "fwd", ("default", "a")) is None
-
-
-def test_plan_capacity_exhaustion_returns_none():
-    plan = api.Plan(capacity=2)
-    impls = ("default", "a")
-    assert plan.slot(OpCell("allreduce", 4, 8), "fwd", impls) == 0
-    assert plan.slot(OpCell("allreduce", 4, 16), "fwd", impls) == 1
-    assert plan.slot(OpCell("allreduce", 4, 32), "fwd", impls) is None
-    assert len(plan) == 2
-
-
-def test_plan_vector_resolves_through_stores_and_ref():
-    plan = api.Plan(capacity=4)
-    cell = OpCell("allreduce", 4, 512)
-    impls = ("default", "allreduce_as_doubling", "allreduce_as_rsb_allgather")
-    s = plan.slot(cell, "decode", impls)
-    vec = plan.vector(base=_store("allreduce_as_doubling"))
-    assert vec.dtype == np.int32 and vec.shape == (4,)
-    assert vec[s] == 1
-    # unknown selection (not admissible at this site) falls back to 0
-    assert plan.vector(base=_store("not_an_impl"))[s] == 0
-    ref = StoreRef(phases={"decode": _store("allreduce_as_rsb_allgather")},
-                   epoch=1)
-    assert plan.vector(ref)[s] == 2
-    assert plan.vector()[s] == 0           # no stores: default
-
-
-def test_plan_explore_flips_to_cyclic_next():
-    plan = api.Plan(capacity=4)
-    cell = OpCell("allreduce", 4, 512)
-    impls = ("default", "allreduce_as_doubling", "allreduce_as_rsb_allgather")
-    s = plan.slot(cell, "fwd", impls)
-    rng = np.random.default_rng(0)
-    vec, explored = plan.explore(eps=1.0, rng=rng,
-                                 base=_store("allreduce_as_doubling"))
-    assert vec[s] == 2                     # 1 -> next in the ring
-    assert explored[(cell, "fwd")] == "allreduce_as_rsb_allgather"
-    vec0, explored0 = plan.explore(eps=0.0, rng=rng,
-                                   base=_store("allreduce_as_doubling"))
-    assert vec0[s] == 1 and not explored0  # eps=0: pure exploitation
-
-
-# ---------------------------------------------------------------------------
-# runtime dispatch: the hot swap happens with ZERO re-jits
+# dispatch: selection order, and a new epoch reaching the next trace
 # ---------------------------------------------------------------------------
 
 
 P = 4
+A, B = "allreduce_as_doubling", "allreduce_as_rsb_allgather"
+
+
+@pytest.mark.parametrize("winner,loser", [
+    ("impl", "force"),
+    ("env", "phase_profiles"),
+    ("profiles", "store_ref"),
+])
+def test_select_precedence(monkeypatch, winner, loser):
+    """Of two sources that both name an impl, the earlier one in the
+    selection order wins; without it, the later one is what dispatches
+    (so the loser was live, not ignored)."""
+    def selected(sources):
+        monkeypatch.delenv("PGTUNE_MODULE", raising=False)
+        kw = {}
+        for src, name in sources.items():
+            if src == "force":
+                kw["force"] = {"allreduce": name}
+            elif src == "env":
+                monkeypatch.setenv("PGTUNE_MODULE", f"allreduce:alg={name}")
+            elif src == "phase_profiles":
+                kw["phase_profiles"] = {api.DEFAULT_PHASE: _store(name)}
+            elif src == "profiles":
+                kw["profiles"] = _store(name)
+            elif src == "store_ref":
+                kw["store_ref"] = StoreRef(base=_store(name), epoch=0)
+        impl = sources.get("impl")
+        with api.tuned(**kw) as ctx:
+            jax.vmap(lambda a: api.allreduce(a, "ax", impl=impl),
+                     axis_name="ax")(jnp.ones((P, 4), jnp.float32))
+        return [r.impl for r in ctx.record]
+
+    assert selected({winner: A, loser: B}) == [A]
+    assert selected({loser: B}) == [B]
 
 
 @pytest.fixture
 def probe_impl(monkeypatch):
     """A marker impl whose output is distinguishable from any real
-    allreduce — proof of which switch branch RAN (not which was traced)."""
+    allreduce — proof of which impl a compiled step RUNS."""
     probe = C.Impl(name="probe_marker", op="allreduce",
                    fn=lambda x, axis, **kw: jnp.full_like(x, 42.0),
                    guideline="EXT", extra_bytes=lambda n, p: 0)
@@ -365,116 +343,29 @@ def probe_impl(monkeypatch):
     return probe
 
 
-def test_plan_dispatch_switches_impl_at_runtime_zero_retrace(probe_impl):
-    plan = api.Plan(capacity=8)
+def test_store_ref_epoch_reaches_the_next_trace(probe_impl):
+    """Dispatch reads the ref at trace time: a step compiled before the
+    swap keeps its impl, and a fresh trace selects and records the new
+    epoch's."""
     ref = StoreRef()
 
-    def step(x, vec):
-        with api.plan_input(vec):
-            return api.allreduce(x, "ax")
+    def step(x):
+        return api.allreduce(x, "ax")
 
-    f = jax.jit(jax.vmap(step, axis_name="ax", in_axes=(0, None)))
     x = jnp.ones((P, 4), jnp.float32)
-    with api.tuned(store_ref=ref, plan=plan):
-        out0 = f(x, jnp.zeros(plan.capacity, jnp.int32))
+    with api.tuned(store_ref=ref) as ctx:
+        f = jax.jit(jax.vmap(step, axis_name="ax"))
+        np.testing.assert_allclose(f(x), np.full((P, 4), float(P)))
+        assert ref.swap(_store("probe_marker"), None, epoch=1)
+        np.testing.assert_allclose(f(x), np.full((P, 4), float(P)))
         assert f._cache_size() == 1
-        sites = plan.sites()
-        assert len(sites) == 1
-        cell, phase, impls = sites[0]
-        assert "probe_marker" in impls
-        np.testing.assert_allclose(out0, np.full((P, 4), float(P)))
-
-        # hot-swap: a generation that selects the probe impl
-        ref.swap(ProfileStore([Profile(op="allreduce", axis_size=P,
-                                       ranges=[Range(1, 1 << 20,
-                                                     "probe_marker")])]),
-                 None, epoch=1)
-        vec1 = jnp.asarray(plan.vector(ref))
-        assert int(vec1.sum()) > 0
-        out1 = f(x, vec1)
-        np.testing.assert_allclose(out1, np.full((P, 4), 42.0))
-        # the defining property: the impl CHANGED, the jit cache did not
-        assert f._cache_size() == 1
-
-
-def test_plan_dispatch_all_real_impls_agree_under_vmap():
-    """Every admissible branch of the runtime switch is a correct
-    allreduce: cycling the plan vector through all of them must
-    reproduce the default's numbers (and never re-trace)."""
-    plan = api.Plan(capacity=8)
-
-    def step(x, vec):
-        with api.plan_input(vec):
-            return api.allreduce(x, "ax")
-
-    f = jax.jit(jax.vmap(step, axis_name="ax", in_axes=(0, None)))
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(P, 8)), jnp.float32)
-    from repro.core import collectives as C
-    from repro.core.selfcheck import rel_err, wire_hops
-    from repro.kernels.quant import wire_tol
-    with api.tuned(store_ref=StoreRef(), plan=plan):
-        ref_out = f(x, jnp.zeros(plan.capacity, jnp.int32))
-        ((_cell, _ph, impls),) = plan.sites()
-        for i in range(1, len(impls)):
-            vec = np.zeros(plan.capacity, np.int32)
-            vec[0] = i
-            out = f(x, jnp.asarray(vec))
-            wd = C.REGISTRY["allreduce"][impls[i]].wire_dtype
-            if wd is not None:
-                # quantized-wire branches are approximate: gate at their
-                # selfcheck tolerance, not exact agreement
-                assert rel_err(out, ref_out) <= wire_tol(
-                    wd, wire_hops("allreduce", P)), impls[i]
-            else:
-                np.testing.assert_allclose(out, ref_out, rtol=2e-5,
-                                           err_msg=impls[i])
-        assert f._cache_size() == 1
-
-
-def test_plan_dispatch_respects_force_and_static_fallback(probe_impl):
-    """Forced ops and capacity-exhausted sites bypass the plan: they
-    dispatch statically like before (recorded with their real impl, not
-    the 'plan' marker)."""
-    plan = api.Plan(capacity=0)            # no capacity: every site static
-
-    def step(x, vec):
-        with api.plan_input(vec):
-            return api.allreduce(x, "ax")
-
-    f = jax.jit(jax.vmap(step, axis_name="ax", in_axes=(0, None)))
-    x = jnp.ones((P, 2), jnp.float32)
-    with api.tuned(store_ref=StoreRef(), plan=plan) as ctx:
-        out = f(x, jnp.zeros(4, jnp.int32))
-    np.testing.assert_allclose(out, np.full((P, 2), float(P)))
-    assert len(plan) == 0
-    assert [r.impl for r in ctx.record] == ["default"]
-
-    plan2 = api.Plan(capacity=8)
-    with api.tuned(force={"allreduce": "probe_marker"}, plan=plan2) as ctx2:
-        out2 = jax.jit(jax.vmap(
-            lambda x, v: step(x, v), axis_name="ax",
-            in_axes=(0, None)))(x, jnp.zeros(8, jnp.int32))
-    np.testing.assert_allclose(out2, np.full((P, 2), 42.0))
-    assert len(plan2) == 0                 # forced op never joins the plan
-    assert [r.impl for r in ctx2.record] == ["probe_marker"]
-
-
-def test_plan_dispatch_records_plan_marker(probe_impl):
-    plan = api.Plan(capacity=8)
-
-    def step(x, vec):
-        with api.plan_input(vec):
-            return api.allreduce(x, "ax")
-
-    with api.tuned(store_ref=StoreRef(), plan=plan) as ctx:
-        jax.jit(jax.vmap(step, axis_name="ax", in_axes=(0, None)))(
-            jnp.ones((P, 2), jnp.float32), jnp.zeros(8, jnp.int32))
-    assert [r.impl for r in ctx.record] == [api.PLAN_IMPL]
+        g = jax.jit(jax.vmap(step, axis_name="ax"))
+        np.testing.assert_allclose(g(x), np.full((P, 4), 42.0))
+    assert [r.impl for r in ctx.record] == ["default", "probe_marker"]
 
 
 # ---------------------------------------------------------------------------
-# feedback: exploration measurements drive the next epoch
+# feedback: fleet measurements drive the next epoch
 # ---------------------------------------------------------------------------
 
 

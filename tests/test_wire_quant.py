@@ -6,9 +6,9 @@ Satellite coverage for the wire_q8/wire_fp8 mock-up family:
 * selfcheck.run_gate demotes a wire impl on an adversarial payload the wire
   format cannot represent (large in-block dynamic range / cancellation),
   and passes it on benign payloads;
-* a demoted impl disappears from every selection surface — static dispatch
-  (api._select falls back to default), runtime plans (_admissible_impls),
-  the tuner (never selected, cost estimates fall back to default);
+* a demoted impl disappears from every selection surface — dispatch
+  (api._select falls back to default, whichever source named the impl)
+  and the tuner (never selected, cost estimates fall back to default);
 * OpCell.dtype round-trips dispatch -> trace JSONL -> geometry profile key
   -> lookup_cell (regression for the dtype-threading audit: a bfloat16
   callsite must not come back as float32).
@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from hypothesis import given, settings, strategies as st
 
 from repro.core import api, collectives as C, costmodel, selfcheck, tuner
-from repro.core.cell import OpCell
+from repro.core.profiles import Profile, ProfileStore, Range, StoreRef
 from repro.core.trace import Trace, TraceEntry
 from repro.kernels.quant import wire_tol
 
@@ -93,29 +93,39 @@ def test_default_impl_cannot_be_demoted():
 # ---------------------------------------------------------------------------
 
 
-def test_demoted_impl_falls_back_to_default_in_dispatch():
+def _wire_q8_store():
+    return ProfileStore([Profile(op="allreduce", axis_size=P,
+                                 ranges=[Range(1, 1 << 20, "wire_q8")])])
+
+
+@pytest.mark.parametrize("source,name,served", [
+    ("force", "wire_q8", "default"),
+    ("profiles", "wire_q8", "default"),
+    ("store_ref", "wire_q8", "default"),
+    ("force", "wire_fp8", "wire_fp8"),      # only the breaker goes
+])
+def test_demoted_impl_falls_back_to_default_in_dispatch(source, name,
+                                                         served):
     C.demote("allreduce", "wire_q8", "tolerance")
+    tuned_kw = {
+        "force": lambda: {"force": {"allreduce": name}},
+        "profiles": lambda: {"profiles": _wire_q8_store()},
+        "store_ref": lambda: {"store_ref": StoreRef(base=_wire_q8_store(),
+                                                    epoch=0)},
+    }[source]()
     x = jnp.asarray(np.random.default_rng(0).normal(size=(P, 8, 4)),
                     jnp.float32)
-    with api.tuned(force={"allreduce": "wire_q8"}) as ctx:
+    with api.tuned(**tuned_kw) as ctx:
         got = jax.vmap(lambda a: api.allreduce(a, "x"), axis_name="x")(x)
-    # the forced-but-demoted impl was swapped for default: exact result
-    np.testing.assert_allclose(np.asarray(got),
-                               np.broadcast_to(np.asarray(x).sum(0),
-                                               x.shape), atol=1e-5)
-    assert [r.impl for r in ctx.record] == ["default"]
-
-
-def test_demoted_impl_left_out_of_admissible_set_and_plans():
-    cell = OpCell("allreduce", P, 1 << 20)
-    with api.tuned() as ctx:
-        before = api._admissible_impls("allreduce", cell, ctx)
-        assert "wire_q8" in before and "wire_fp8" in before
-        C.demote("allreduce", "wire_q8", "tolerance")
-        after = api._admissible_impls("allreduce", cell, ctx)
-    assert "wire_q8" not in after
-    assert "wire_fp8" in after                   # only the breaker goes
-    assert set(before) - set(after) == {"wire_q8"}
+    assert [r.impl for r in ctx.record] == [served]
+    want = np.broadcast_to(np.asarray(x).sum(0), x.shape)
+    if served == "default":
+        # the selected-but-demoted impl was swapped for default: exact
+        np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
+    else:
+        wd = C.REGISTRY["allreduce"][served].wire_dtype
+        assert selfcheck.rel_err(got, want) <= wire_tol(
+            wd, selfcheck.wire_hops("allreduce", P))
 
 
 def test_tuner_never_selects_demoted_wire_impls():
